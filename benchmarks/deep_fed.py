@@ -71,5 +71,8 @@ def run(quick: bool = False, rounds: int | None = None, alpha: float = 0.2):
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache(__file__)
     for r in run(quick=True):
         print(f"{r[0]},{r[1]:.0f},{r[2]}")

@@ -174,6 +174,9 @@ def run(quick: bool = False) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache(__file__)
     import argparse
 
     ap = argparse.ArgumentParser()

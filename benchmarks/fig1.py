@@ -108,6 +108,9 @@ def run(quick: bool = False):
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache(__file__)
     import json
 
     print(json.dumps(run(quick=True), indent=1))

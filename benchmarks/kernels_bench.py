@@ -70,5 +70,8 @@ def run(quick: bool = False):
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache(__file__)
     for r in run(quick=True):
         print(f"{r[0]},{r[1]:.1f},{r[2]}")

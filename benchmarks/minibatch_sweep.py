@@ -60,6 +60,9 @@ def run(quick: bool = False):
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache(__file__)
     print("b,rounds_to_eps,comm_to_eps")
     for b, r, c in run(quick=True):
         print(f"{b},{r},{c}")

@@ -18,10 +18,13 @@ jax.config.update("jax_enable_x64", True)
 
 
 def main() -> None:
+    from repro.utils.compile_cache import enable_compile_cache
+
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true", help="paper-scale panels (slow)")
     args = ap.parse_args()
     quick = not args.full
+    enable_compile_cache(__file__)
 
     print("name,us_per_call,derived")
 

@@ -138,6 +138,9 @@ def run(quick: bool = False):
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache(__file__)
     import argparse
 
     ap = argparse.ArgumentParser()

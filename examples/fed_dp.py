@@ -20,6 +20,9 @@ import numpy as np
 from repro.core import measure_constants, predict_comm_for
 from repro.experiments import run_batch
 from repro.problems import make_dp_a9a_problem
+from repro.utils.compile_cache import enable_compile_cache
+
+enable_compile_cache(__file__)
 
 M = 10
 NUM_STEPS = 400

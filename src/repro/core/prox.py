@@ -62,6 +62,8 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.precision import mm
+
 
 def prox_gd(
     grad_fn: Callable[[jax.Array], jax.Array],
@@ -96,7 +98,7 @@ def prox_gd_batched(
     y0: jax.Array | None = None,
     *,
     use_kernel: bool = False,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Algorithm 7 across a whole sweep batch at once.
 
@@ -106,6 +108,8 @@ def prox_gd_batched(
     through the fused batched Pallas kernel (`kernels.prox_update_batched`) so
     the bandwidth-bound `y - beta (g + (y - z)/eta)` stays one launch per GD
     step for the entire sweep; otherwise it is the identical jnp expression.
+    `interpret=None` compiles the kernel on TPU and interprets it elsewhere
+    (`kernels.backend.resolve_interpret`).
     """
     B = z.shape[0]
     eta = jnp.broadcast_to(jnp.asarray(eta, z.dtype), (B,))
@@ -293,16 +297,16 @@ def prox_newton_cg(
         def body(carry):
             d, r, p, rs, k = carry
             Hp = hvp(p)
-            alpha = rs / (p @ Hp)
+            alpha = rs / mm(p, Hp)
             d = d + alpha * p
             r = r - alpha * Hp
-            rs_new = r @ r
+            rs_new = mm(r, r)
             p = r + (rs_new / rs) * p
             return (d, r, p, rs_new, k + 1)
 
         r0 = -g
         d, _, _, _, _ = jax.lax.while_loop(
-            cond, body, (jnp.zeros_like(g), r0, r0, r0 @ r0, jnp.asarray(0))
+            cond, body, (jnp.zeros_like(g), r0, r0, mm(r0, r0), jnp.asarray(0))
         )
         return d
 

@@ -730,7 +730,7 @@ def fused_oracle_kind(problem) -> str:
     )
 
 
-def prox_gd_fused(problem, m, z, eta, L, prox_steps: int, interpret: bool):
+def prox_gd_fused(problem, m, z, eta, L, prox_steps: int, interpret: bool | None):
     """The batched Algorithm-7 solve of one fused round: per-row sampled
     client ``m`` (R,), targets ``z`` (R, d), per-row eta/L scalars.  Rows are
     trials for single-client rounds and trial x cohort pairs for minibatch.
@@ -771,7 +771,7 @@ def _rows(a):
 
 
 def _fused_ops(algo: str, problem, hp, x_star, x0, B: int, *,
-               inner_steps: int, interpret: bool,
+               inner_steps: int, interpret: bool | None,
                cohort_size: int | None = None, channel=None) -> RoundOps:
     """Bind one algorithm's fused substrate: vmapped sampling + Pallas prox."""
     dtype = x0.dtype
@@ -828,7 +828,7 @@ def _fused_ops(algo: str, problem, hp, x_star, x0, B: int, *,
 
 def batched_scan(
     algo: str, problem, x0, x_star, keys, hp, *,
-    num_steps: int, inner_steps: int, interpret: bool, **static,
+    num_steps: int, inner_steps: int, interpret: bool | None = None, **static,
 ) -> RunResult:
     """The fused substrate's sweep driver: one hand-batched scan over (B, d)
     state for the whole trial batch.  ``inner_steps`` is the algorithm's
@@ -853,7 +853,7 @@ def batched_scan(
 
 def _catalyzed_batched_scan(
     problem, x0, x_star, keys, hp, *,
-    num_outer: int, num_steps: int, inner_steps: int, interpret: bool,
+    num_outer: int, num_steps: int, inner_steps: int, interpret: bool | None,
     channel=None,
 ) -> RunResult:
     """Catalyzed SVRP on the fused substrate: the outer Catalyst recurrence
@@ -1073,7 +1073,7 @@ class ClientShardedOps(RoundOps):
 def make_client_sharded_ops(
     algo: str, local_problem, x0, x_star, hp, *,
     axis: str, num_clients: int, valid, num_trials: int,
-    fused: bool = False, inner_steps: int | None = None, interpret: bool = True,
+    fused: bool = False, inner_steps: int | None = None, interpret: bool | None = None,
     prox_solver: str = "exact", prox_steps: int = 50, prox_tol: float = 1e-10,
     batch_clients: int | None = None, local_steps: int | None = None,
     channel=None,

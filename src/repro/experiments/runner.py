@@ -293,9 +293,10 @@ def run_batch(
     svrp_minibatch/catalyzed_svrp with prox_solver="gd", and deep_svrp
     always) switches to the fused substrate (`rounds.batched_scan`):
     hand-batched `(B, d)` state, local solves through the batched Pallas
-    kernels, batch-aware anchor refresh; `interpret` (fused-only) selects the
-    kernel's interpreter mode and defaults to True, the CPU-safe choice —
-    pass interpret=False on real TPU hardware to compile the kernel.
+    kernels, batch-aware anchor refresh.  The kernels are compiled on a TPU
+    and run in the Pallas interpreter on every other backend
+    (`kernels.backend.resolve_interpret`); `interpret` (fused-only) overrides
+    that rule and is rarely needed.
 
     `shard="data"` additionally lays the `(B,)` trial axis over the device
     mesh (`devices` defaults to all local devices): B is padded up to a
@@ -363,12 +364,11 @@ def run_batch(
                     f"{algo}: fused=True requires a fusable algo with prox_solver='gd'"
                 )
             fused_oracle_kind(problem)
-            interpret = True if interpret is None else interpret
         elif interpret is not None:
             raise ValueError("interpret only applies to the fused=True Pallas path")
         res = _run_client_sharded(
             algo, tuple(sorted(cfg.items())), problem, x0, x_star, keys, hp,
-            devices=devices, fused=fused, interpret=bool(interpret),
+            devices=devices, fused=fused, interpret=interpret,
         )
         return BatchResult(
             dist_sq=res.dist_sq,
@@ -386,7 +386,6 @@ def run_batch(
                 f"{algo}: fused=True requires a fusable algo with prox_solver='gd'"
             )
         fused_oracle_kind(problem)  # clear trace-time error for unsupported problems
-        interpret = True if interpret is None else interpret
         static_items = tuple(sorted(cfg.items()))
         body = _fused_body(algo, static_items, interpret)
         runner = _fused_runner(algo, static_items, interpret)
@@ -476,8 +475,7 @@ def _sharded_runner(body: Callable, devices: tuple) -> Callable:
 
     The body runs fully locally on each device's trial block — the lowered
     module contains ZERO cross-device collectives; PRNG keys travel as uint32
-    key-data (typed key arrays don't cross the shard_map boundary on older
-    jax).  Cached per (body, devices) so repeated sweeps of the same shape
+    key-data (`jax.random.key_data`).  Cached per (body, devices) so repeated sweeps of the same shape
     compile once, mirroring `_batched_runner`.
     """
     from repro.launch.mesh import make_sweep_mesh
@@ -526,7 +524,8 @@ def _run_sharded(body, problem, x0, x_star, keys, hp, devices) -> RunResult:
 
 @functools.lru_cache(maxsize=None)
 def _client_body(
-    algo: str, static_items: tuple, num_clients: int, fused: bool, interpret: bool
+    algo: str, static_items: tuple, num_clients: int, fused: bool,
+    interpret: bool | None,
 ) -> Callable:
     """The per-device body of the client-sharded path: `(local_problem,
     valid, x0, x_star, keys, hp) -> RunResult`, already inside shard_map."""
@@ -623,7 +622,7 @@ def _run_client_sharded(
 
 
 @functools.lru_cache(maxsize=None)
-def _fused_body(algo: str, static_items: tuple, interpret: bool) -> Callable:
+def _fused_body(algo: str, static_items: tuple, interpret: bool | None) -> Callable:
     """The unjitted fused-substrate driver (jitted by `_fused_runner`;
     shard-mapped raw by the sharded path so each device runs its own fused
     block).  `static_items` is the algo's full sorted static config — the
@@ -646,5 +645,5 @@ def _fused_body(algo: str, static_items: tuple, interpret: bool) -> Callable:
 
 
 @functools.lru_cache(maxsize=None)
-def _fused_runner(algo: str, static_items: tuple, interpret: bool) -> Callable:
+def _fused_runner(algo: str, static_items: tuple, interpret: bool | None) -> Callable:
     return jax.jit(_fused_body(algo, static_items, interpret))

@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.backend import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -66,7 +68,7 @@ def decode_attention(
     valid,  # (S,) bool
     *,
     block_s: int = 512,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     B, _, H, Dh = q.shape
     _, S, KVH, _ = k_cache.shape
@@ -99,6 +101,6 @@ def decode_attention(
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, Dh), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qt, kt, vt, vmask)
     return out.reshape(B, 1, H, Dh)

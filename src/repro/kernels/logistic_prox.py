@@ -19,11 +19,13 @@ row) and padded columns stay 0 from the x0 = z start — no masks needed.
 
 Grid is `(B,)`: program b owns trial b's (n_pad, d_pad) block and runs the
 full `num_steps` fori_loop in VMEM; per-trial scalars (beta_b, 1/eta_b, lam,
-1/n) ride in a `(B, 4)` operand.  VMEM budget is the A block: n_pad * d_pad *
-itemsize (a9a at f32: 2048 * 128 * 4 = 1 MiB — comfortably resident).
-Validated in interpret mode against `ref.logistic_prox_gd_batched`; real-TPU
-compile (interpret=False) rides the same open ROADMAP item as the other
-kernels.
+1/n) ride in a `(4, B)` SMEM table, and the per-trial rows (target, start,
+result) are laid out `(B, 1, d_pad)` so every VMEM block's last two dims are
+the array's (Mosaic's block-tiling rule, for any B).  VMEM budget is the A
+block: n_pad * d_pad * itemsize (a9a at f32: 1632 * 128 * 4 = 0.8 MiB,
+double-buffered).  Validated in interpret mode against
+`ref.logistic_prox_gd_batched`; compiled for a v5e by
+tests/test_tpu_compile.py.
 """
 from __future__ import annotations
 
@@ -32,6 +34,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.core.precision import HIGHEST
+from repro.kernels.backend import resolve_interpret
 
 _LANES = 128
 _SUBLANES = 8
@@ -41,23 +47,24 @@ def _round_up(x: int, mult: int) -> int:
     return -(-x // mult) * mult
 
 
-def _logistic_prox_kernel(a_ref, z_ref, x0_ref, s_ref, o_ref, *, num_steps: int):
+def _logistic_prox_kernel(s_ref, a_ref, z_ref, x0_ref, o_ref, *, num_steps: int):
+    b = pl.program_id(0)
     A = a_ref[0]  # (n_pad, d_pad) — this trial's label-signed features
-    z = z_ref[...]  # (1, d_pad) prox target
-    x0 = x0_ref[...]  # (1, d_pad) GD start (== z except for the DP noise fold)
-    beta = s_ref[0, 0]
-    inv_eta = s_ref[0, 1]
-    lam = s_ref[0, 2]
-    inv_n = s_ref[0, 3]
+    z = z_ref[0]  # (1, d_pad) prox target
+    x0 = x0_ref[0]  # (1, d_pad) GD start (== z except for the DP noise fold)
+    beta = s_ref[0, b]  # this trial's scalars: column b of the SMEM (4, B) table
+    inv_eta = s_ref[1, b]
+    lam = s_ref[2, b]
+    inv_n = s_ref[3, b]
 
     def gd_step(_, x):  # x: (1, d_pad)
         # t = x A' : (1, n_pad) margins; sigmoid(-t) = 0.5 (tanh(-t/2) + 1)
-        t = jax.lax.dot_general(x, A, (((1,), (1,)), ((), ())))
+        t = jax.lax.dot_general(x, A, (((1,), (1,)), ((), ())), precision=HIGHEST)
         u = 0.5 * (jnp.tanh(-0.5 * t) + 1.0)
-        g = -inv_n * jnp.dot(u, A) + lam * x
+        g = -inv_n * jnp.dot(u, A, precision=HIGHEST) + lam * x
         return x - beta * (g + (x - z) * inv_eta)
 
-    o_ref[...] = jax.lax.fori_loop(0, num_steps, gd_step, x0)
+    o_ref[0] = jax.lax.fori_loop(0, num_steps, gd_step, x0)
 
 
 @functools.partial(jax.jit, static_argnames=("num_steps", "interpret"))
@@ -70,7 +77,7 @@ def logistic_prox_gd_batched(
     num_steps: int,
     *,
     y0: jax.Array | None = None,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """`num_steps` of Algorithm 7 on the `(B, n, d)` logistic oracle, one launch.
 
@@ -88,30 +95,33 @@ def logistic_prox_gd_batched(
     d_pad = _round_up(d, _LANES)
     n_pad = _round_up(n, _SUBLANES)
 
+    def rows(v):  # (B, d) -> (B, 1, d_pad): each block's last two dims are full
+        return jnp.pad(v.astype(dtype), ((0, 0), (0, d_pad - d)))[:, None, :]
+
     A_p = jnp.pad(A, ((0, 0), (0, n_pad - n), (0, d_pad - d)))
-    z_p = jnp.pad(z.astype(dtype), ((0, 0), (0, d_pad - d)))
-    x0_p = z_p if y0 is None else jnp.pad(y0.astype(dtype), ((0, 0), (0, d_pad - d)))
+    z_p = rows(z)
+    x0_p = z_p if y0 is None else rows(y0)
     scalars = jnp.stack(
         [
             jnp.broadcast_to(jnp.asarray(beta, dtype), (B,)),
             jnp.broadcast_to(jnp.asarray(inv_eta, dtype), (B,)),
             jnp.full((B,), lam, dtype),
             jnp.full((B,), 1.0 / n, dtype),
-        ],
-        axis=-1,
-    )  # (B, 4)
+        ]
+    )  # (4, B): program b reads column b
 
+    row = pl.BlockSpec((1, 1, d_pad), lambda b: (b, 0, 0))
     out = pl.pallas_call(
         functools.partial(_logistic_prox_kernel, num_steps=num_steps),
         grid=(B,),
         in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, n_pad, d_pad), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, d_pad), lambda b: (b, 0)),
-            pl.BlockSpec((1, d_pad), lambda b: (b, 0)),
-            pl.BlockSpec((1, 4), lambda b: (b, 0)),
+            row,
+            row,
         ],
-        out_specs=pl.BlockSpec((1, d_pad), lambda b: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, d_pad), dtype),
-        interpret=interpret,
-    )(A_p, z_p, x0_p, scalars)
-    return out[:, :d]
+        out_specs=row,
+        out_shape=jax.ShapeDtypeStruct((B, 1, d_pad), dtype),
+        interpret=resolve_interpret(interpret),
+    )(scalars, A_p, z_p, x0_p)
+    return out[:, 0, :d]

@@ -15,14 +15,14 @@ import jax
 import jax.numpy as jnp
 
 _USE_PALLAS = os.environ.get("REPRO_USE_PALLAS", "0") == "1"
-_PALLAS_INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") == "1"
 
 
-def use_pallas(enable: bool = True, interpret: bool | None = None) -> None:
-    global _USE_PALLAS, _PALLAS_INTERPRET
+def use_pallas(enable: bool = True) -> None:
+    """Route the ops below through their Pallas kernels, which compile on a
+    TPU and run in the interpreter elsewhere
+    (`kernels.backend.resolve_interpret`)."""
+    global _USE_PALLAS
     _USE_PALLAS = enable
-    if interpret is not None:
-        _PALLAS_INTERPRET = interpret
 
 
 def pallas_enabled() -> bool:
@@ -224,7 +224,6 @@ def attention(q, k, v, *, causal=True, sliding_window=None, q_offset=0, chunk=51
             causal=causal,
             sliding_window=sliding_window,
             q_offset=q_offset,
-            interpret=_PALLAS_INTERPRET,
         )
     return _chunked_attention(q, k, v, causal, sliding_window, q_offset, chunk)
 
@@ -235,7 +234,7 @@ def decode_attention(q, k_cache, v_cache, valid):
     if _USE_PALLAS:
         from repro.kernels import decode_attention as dk
 
-        return dk.decode_attention(q, k_cache, v_cache, valid, interpret=_PALLAS_INTERPRET)
+        return dk.decode_attention(q, k_cache, v_cache, valid)
     B, _, H, Dh = q.shape
     _, S, KVH, _ = k_cache.shape
     G = H // KVH
@@ -254,7 +253,7 @@ def rwkv6_scan(r, k, v, w, u, state0=None):
     if _USE_PALLAS:
         from repro.kernels import rwkv6_scan as rk
 
-        return rk.rwkv6_scan(r, k, v, w, u, state0=state0, interpret=_PALLAS_INTERPRET)
+        return rk.rwkv6_scan(r, k, v, w, u, state0=state0)
     from repro.kernels import ref
 
     return ref.rwkv6_scan(r, k, v, w, u, state0=state0)
@@ -265,7 +264,7 @@ def ssm_scan(x, dt, A, B_mat, C_mat, D, state0=None):
     if _USE_PALLAS:
         from repro.kernels import ssm_scan as sk
 
-        return sk.ssm_scan(x, dt, A, B_mat, C_mat, D, state0=state0, interpret=_PALLAS_INTERPRET)
+        return sk.ssm_scan(x, dt, A, B_mat, C_mat, D, state0=state0)
     from repro.kernels import _ssm_chunked
 
     return _ssm_chunked.ssm_scan_chunked(x, dt, A, B_mat, C_mat, D, state0=state0)
@@ -277,7 +276,7 @@ def prox_update(y, g, z, local_lr, inv_eta):
     if _USE_PALLAS:
         from repro.kernels import prox_update as pk
 
-        return pk.prox_update(y, g, z, local_lr, inv_eta, interpret=_PALLAS_INTERPRET)
+        return pk.prox_update(y, g, z, local_lr, inv_eta)
     from repro.kernels import ref
 
     return ref.prox_update(y, g, z, local_lr, inv_eta)
@@ -320,7 +319,7 @@ def prox_update_tree(y_tree, g_tree, z_tree, local_lr, inv_eta):
         yc = jnp.concatenate([leaves_y[i].reshape(-1) for i in idxs])
         gc = jnp.concatenate([leaves_g[i].reshape(-1).astype(dt) for i in idxs])
         zc = jnp.concatenate([leaves_z[i].reshape(-1) for i in idxs])
-        upd = pk.prox_update(yc, gc, zc, local_lr, inv_eta, interpret=_PALLAS_INTERPRET)
+        upd = pk.prox_update(yc, gc, zc, local_lr, inv_eta)
         off = 0
         for i, n in zip(idxs, sizes):
             out[i] = upd[off:off + n].reshape(leaves_y[i].shape)

@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.backend import resolve_interpret
+
 
 def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sout_ref, S_scr, *, block_t):
     it = pl.program_id(2)
@@ -55,7 +57,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sout_ref, S_sc
 
 
 @functools.partial(jax.jit, static_argnames=("block_t", "interpret"))
-def rwkv6_scan(r, k, v, w, u, state0=None, *, block_t: int = 64, interpret: bool = True):
+def rwkv6_scan(r, k, v, w, u, state0=None, *, block_t: int = 64, interpret: bool | None = None):
     """Same contract as ref.rwkv6_scan: r,k,w (B,T,H,K); v (B,T,H,V); u (H,K).
     Returns (y (B,T,H,V), final state (B,H,K,V))."""
     B, T, H, K = r.shape
@@ -95,7 +97,7 @@ def rwkv6_scan(r, k, v, w, u, state0=None, *, block_t: int = 64, interpret: bool
             jax.ShapeDtypeStruct((B, H, K, V), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((K, V), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(rt, kt2, vt, wt, u, state0)
     y = jnp.moveaxis(y[:, :, :T], 1, 2)  # (B,T,H,V)
     return y, s_out

@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.backend import resolve_interpret
+
 
 def _ssm_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, h0_ref, y_ref, hout_ref, h_scr, *, block_t):
     it = pl.program_id(2)
@@ -65,7 +67,7 @@ def _ssm_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, h0_ref, y_ref, hout_r
 
 
 @functools.partial(jax.jit, static_argnames=("block_t", "interpret"))
-def ssm_scan(x, dt, A, B_mat, C_mat, D, state0=None, *, block_t: int = 128, interpret: bool = True):
+def ssm_scan(x, dt, A, B_mat, C_mat, D, state0=None, *, block_t: int = 128, interpret: bool | None = None):
     """Same contract as ref.ssm_scan: x (B,T,H,P); dt (B,T,H); A,D (H,);
     B_mat, C_mat (B,T,N).  Returns (y (B,T,H,P), final state (B,H,P,N))."""
     Bb, T, H, P = x.shape
@@ -106,7 +108,7 @@ def ssm_scan(x, dt, A, B_mat, C_mat, D, state0=None, *, block_t: int = 128, inte
             jax.ShapeDtypeStruct((Bb, H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xt, dtt, A2, B_mat, C_mat, D2, state0)
     y = jnp.moveaxis(y[:, :, :T], 1, 2)
     return y, h_out

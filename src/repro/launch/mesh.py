@@ -7,16 +7,10 @@ default).
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5 — older jaxlibs default every axis to Auto anyway
-    from jax.sharding import AxisType
-except ImportError:
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 

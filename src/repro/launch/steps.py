@@ -122,10 +122,7 @@ class MeshStep:
         self.mesh = mesh
 
     def lower(self, *args, **kwargs):
-        # jax >= 0.6 spells the active-mesh context jax.set_mesh; on older
-        # releases the Mesh object itself is the context manager.
-        ctx = jax.set_mesh(self.mesh) if hasattr(jax, "set_mesh") else self.mesh
-        with ctx:
+        with jax.set_mesh(self.mesh):
             return self._fn.lower(*args, **kwargs)
 
     def __call__(self, *args, **kwargs):

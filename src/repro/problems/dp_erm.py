@@ -74,6 +74,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from repro.core.precision import mm
 from repro.problems.logistic import LogisticProblem
 from repro.problems.quadratic import QuadraticProblem
 
@@ -205,10 +206,10 @@ class DPLogisticProblem(LogisticProblem):
 
     # --- noised oracles (linear term has zero Hessian) -----------------------
     def loss(self, m, x):
-        return super().loss(m, x) + jnp.take(self.dp_shift, m, axis=0) @ x
+        return super().loss(m, x) + mm(jnp.take(self.dp_shift, m, axis=0), x)
 
     def full_loss(self, x):
-        return super().full_loss(x) + jnp.mean(self.dp_shift, axis=0) @ x
+        return super().full_loss(x) + mm(jnp.mean(self.dp_shift, axis=0), x)
 
     def grad(self, m, x):
         return super().grad(m, x) + jnp.take(self.dp_shift, m, axis=0)

@@ -32,6 +32,33 @@ import jax.numpy as jnp
 import numpy as np
 
 
+def _one_row_at_a_time(f: Callable) -> Callable:
+    """`f`, except that under `vmap` it runs as a `lax.map` over the batch.
+
+    The engine vmaps client oracles over clients and trials, and every row
+    carries its own parameter vector.  Batching the model over per-row
+    weights makes XLA's TPU compile grow with the model: for the 100m preset
+    (124.7M parameters) over 4 clients, compiling the vmapped loss for a v5e
+    took 154 s and 7.4 GB of host memory, the vmapped gradient 138 s and
+    8.8 GB, and a whole deep_svrp round more than the 40 GiB host of a v5e
+    machine; the mapped gradient compiles in 10 s and 1.5 GB.  Evaluating the
+    rows one at a time also keeps device memory at one model's activations.
+    Unbatched arguments are shared by every row, not broadcast.
+    """
+    f = jax.custom_batching.custom_vmap(f)
+
+    @f.def_vmap
+    def _rule(axis_size, in_batched, *args):
+        def row(batched):
+            it = iter(batched)
+            return f(*(next(it) if b else a for a, b in zip(args, in_batched)))
+
+        rows = tuple(a for a, b in zip(args, in_batched) if b)
+        return jax.lax.map(row, rows), True
+
+    return f
+
+
 @partial(
     jax.tree_util.register_dataclass,
     data_fields=["tokens", "labels"],
@@ -62,23 +89,30 @@ class FedLMProblem:
         params = self.unravel(x)
         return M.loss_fn(params, self.cfg, {"tokens": tokens, "labels": labels})
 
+    def _loss_fn(self) -> Callable:
+        return _one_row_at_a_time(self._client_loss)
+
+    def _grad_fn(self) -> Callable:
+        return _one_row_at_a_time(jax.grad(self._client_loss))
+
     def loss(self, m: jax.Array, x: jax.Array) -> jax.Array:
-        return self._client_loss(
+        return self._loss_fn()(
             x, jnp.take(self.tokens, m, axis=0), jnp.take(self.labels, m, axis=0)
         )
 
     def grad(self, m: jax.Array, x: jax.Array) -> jax.Array:
-        return jax.grad(self._client_loss)(
+        return self._grad_fn()(
             x, jnp.take(self.tokens, m, axis=0), jnp.take(self.labels, m, axis=0)
         )
 
     def full_grad(self, x: jax.Array) -> jax.Array:
         """Across-client mean gradient — a sequential scan over clients so
         peak memory stays one model-gradient regardless of M."""
+        grad = self._grad_fn()
 
         def body(acc, mb):
             tok, lab = mb
-            return acc + jax.grad(self._client_loss)(x, tok, lab), None
+            return acc + grad(x, tok, lab), None
 
         acc, _ = jax.lax.scan(
             body, jnp.zeros_like(x), (self.tokens, self.labels)
@@ -89,9 +123,11 @@ class FedLMProblem:
         """Across-client mean LM loss — the engine's dist_sq column for
         problems with no computable x_star (`RoundOps.dist_sq` hook)."""
 
+        loss = self._loss_fn()
+
         def body(acc, mb):
             tok, lab = mb
-            return acc + self._client_loss(x, tok, lab), None
+            return acc + loss(x, tok, lab), None
 
         acc, _ = jax.lax.scan(
             body, jnp.zeros((), x.dtype), (self.tokens, self.labels)

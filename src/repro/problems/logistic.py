@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.precision import HIGHEST, mm
 from repro.core.prox import prox_newton
 
 
@@ -58,33 +59,33 @@ class LogisticProblem:
     def loss(self, m: jax.Array, x: jax.Array) -> jax.Array:
         Z_m = jnp.take(self.Z, m, axis=0)
         y_m = jnp.take(self.y, m, axis=0)
-        t = y_m * (Z_m @ x)
-        return jnp.mean(jnp.logaddexp(0.0, -t)) + 0.5 * self.lam * x @ x
+        t = y_m * mm(Z_m, x)
+        return jnp.mean(jnp.logaddexp(0.0, -t)) + 0.5 * self.lam * mm(x, x)
 
     def grad(self, m: jax.Array, x: jax.Array) -> jax.Array:
         Z_m = jnp.take(self.Z, m, axis=0)
         y_m = jnp.take(self.y, m, axis=0)
-        t = y_m * (Z_m @ x)
+        t = y_m * mm(Z_m, x)
         w = -y_m * _sigmoid(-t)  # d/dt log(1+e^-t) = -sigmoid(-t)
-        return Z_m.T @ w / Z_m.shape[0] + self.lam * x
+        return mm(Z_m.T, w) / Z_m.shape[0] + self.lam * x
 
     def full_loss(self, x: jax.Array) -> jax.Array:
-        t = self.y * jnp.einsum("mnd,d->mn", self.Z, x)
-        return jnp.mean(jnp.logaddexp(0.0, -t)) + 0.5 * self.lam * x @ x
+        t = self.y * jnp.einsum("mnd,d->mn", self.Z, x, precision=HIGHEST)
+        return jnp.mean(jnp.logaddexp(0.0, -t)) + 0.5 * self.lam * mm(x, x)
 
     def full_grad(self, x: jax.Array) -> jax.Array:
-        t = self.y * jnp.einsum("mnd,d->mn", self.Z, x)
+        t = self.y * jnp.einsum("mnd,d->mn", self.Z, x, precision=HIGHEST)
         w = -self.y * _sigmoid(-t)
         M, n, _ = self.Z.shape
-        return jnp.einsum("mnd,mn->d", self.Z, w) / (M * n) + self.lam * x
+        return jnp.einsum("mnd,mn->d", self.Z, w, precision=HIGHEST) / (M * n) + self.lam * x
 
     def hessian(self, m: jax.Array, x: jax.Array) -> jax.Array:
         Z_m = jnp.take(self.Z, m, axis=0)
         y_m = jnp.take(self.y, m, axis=0)
-        t = y_m * (Z_m @ x)
+        t = y_m * mm(Z_m, x)
         s = _sigmoid(t) * _sigmoid(-t)
         d = self.dim
-        return (Z_m * s[:, None]).T @ Z_m / Z_m.shape[0] + self.lam * jnp.eye(d, dtype=x.dtype)
+        return mm((Z_m * s[:, None]).T, Z_m) / Z_m.shape[0] + self.lam * jnp.eye(d, dtype=x.dtype)
 
     def local_oracle(self, m: jax.Array):
         """(grad_fn, hess_fn) of client m with the data gather HOISTED.
@@ -100,13 +101,13 @@ class LogisticProblem:
         eye = self.lam * jnp.eye(self.dim, dtype=self.Z.dtype)
 
         def grad_fn(x):
-            u = _sigmoid(-(A @ x))  # sigmoid of minus-margins
-            return -(A.T @ u) / n + self.lam * x
+            u = _sigmoid(-mm(A, x))  # sigmoid of minus-margins
+            return -mm(A.T, u) / n + self.lam * x
 
         def hess_fn(x):
-            t = A @ x
+            t = mm(A, x)
             s = _sigmoid(t) * _sigmoid(-t)
-            return (A * s[:, None]).T @ A / n + eye
+            return mm((A * s[:, None]).T, A) / n + eye
 
         return grad_fn, hess_fn
 
@@ -136,7 +137,7 @@ class LogisticProblem:
     def smoothness(self) -> jax.Array:
         """L <= lambda_max((1/(4 M n)) sum Z'Z) + lam — the standard bound."""
         M, n, _ = self.Z.shape
-        G = jnp.einsum("mni,mnj->ij", self.Z, self.Z) / (M * n)
+        G = jnp.einsum("mni,mnj->ij", self.Z, self.Z, precision=HIGHEST) / (M * n)
         return 0.25 * jnp.linalg.eigvalsh(G)[-1] + self.lam
 
     def smoothness_max(self) -> jax.Array:
@@ -145,7 +146,7 @@ class LogisticProblem:
         n = self.Z.shape[1]
 
         def client_L(Z_m):
-            G = Z_m.T @ Z_m / (4.0 * n)
+            G = mm(Z_m.T, Z_m) / (4.0 * n)
             return jnp.linalg.eigvalsh(G)[-1] + self.lam
 
         return jnp.max(jax.vmap(client_L)(self.Z))
@@ -157,7 +158,7 @@ class LogisticProblem:
         """Measured delta(x): sqrt(lambda_max((1/M) sum (H_m(x) - Hbar(x))^2))."""
         H = jax.vmap(lambda m: self.hessian(m, x))(jnp.arange(self.num_clients))
         E = H - jnp.mean(H, axis=0, keepdims=True)
-        S = jnp.mean(jnp.einsum("mij,mjk->mik", E, E), axis=0)
+        S = jnp.mean(jnp.einsum("mij,mjk->mik", E, E, precision=HIGHEST), axis=0)
         return jnp.sqrt(jnp.linalg.eigvalsh(S)[-1])
 
     def similarity_max_at(self, x: jax.Array) -> jax.Array:

@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.precision import mm
+
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
@@ -58,18 +60,18 @@ class QuadraticProblem:
         """Gradient of f_m at x (m may be a traced integer)."""
         A_m = jnp.take(self.A, m, axis=0)
         b_m = jnp.take(self.b, m, axis=0)
-        return A_m @ x - b_m
+        return mm(A_m, x) - b_m
 
     def full_grad(self, x: jax.Array) -> jax.Array:
-        return self.A_bar @ x - self.b_bar
+        return mm(self.A_bar, x) - self.b_bar
 
     def loss(self, m: jax.Array, x: jax.Array) -> jax.Array:
         A_m = jnp.take(self.A, m, axis=0)
         b_m = jnp.take(self.b, m, axis=0)
-        return 0.5 * x @ (A_m @ x) - b_m @ x
+        return 0.5 * mm(x, mm(A_m, x)) - mm(b_m, x)
 
     def full_loss(self, x: jax.Array) -> jax.Array:
-        return 0.5 * x @ (self.A_bar @ x) - self.b_bar @ x
+        return 0.5 * mm(x, mm(self.A_bar, x)) - mm(self.b_bar, x)
 
     def hessian(self, m: jax.Array, x: jax.Array) -> jax.Array:
         """Constant client Hessian A_m (uniform oracle for the Newton solvers,
@@ -82,7 +84,7 @@ class QuadraticProblem:
         out of iterative prox solvers (see LogisticProblem.local_oracle)."""
         A_m = jnp.take(self.A, m, axis=0)
         b_m = jnp.take(self.b, m, axis=0)
-        return (lambda x: A_m @ x - b_m), (lambda x: A_m)
+        return (lambda x: mm(A_m, x) - b_m), (lambda x: A_m)
 
     def prox(self, m: jax.Array, z: jax.Array, eta: jax.Array) -> jax.Array:
         """Exact prox_{eta f_m}(z) = (I + eta A_m)^{-1}(z + eta b_m)."""
@@ -115,7 +117,7 @@ class QuadraticProblem:
         lam_m = jnp.take(lam, m, axis=0)
         b_m = jnp.take(self.b, m, axis=0)
         rhs = z + eta * b_m
-        return Q_m @ ((Q_m.T @ rhs) / (1.0 + eta * lam_m))
+        return mm(Q_m, mm(Q_m.T, rhs) / (1.0 + eta * lam_m))
 
     def shifted(self, gamma: float, y: jax.Array) -> "QuadraticProblem":
         """Catalyst subproblem  h_t,m(x) = f_m(x) + gamma/2 ||x - y||^2."""
@@ -141,7 +143,7 @@ class QuadraticProblem:
     def similarity(self) -> jax.Array:
         """Exact delta:  delta^2 = lambda_max((1/M) sum (A_m - Abar)^2)."""
         E = self.A - self.A_bar[None]
-        S = jnp.mean(jax.vmap(lambda e: e @ e)(E), axis=0)
+        S = jnp.mean(jax.vmap(lambda e: mm(e, e))(E), axis=0)
         return jnp.sqrt(jnp.linalg.eigvalsh(S)[-1])
 
     def similarity_max(self) -> jax.Array:
@@ -156,7 +158,7 @@ class QuadraticProblem:
     def grad_noise_at_opt(self) -> jax.Array:
         """sigma_*^2 = E_m ||grad f_m(x_*)||^2 (Theorem 1's noise constant)."""
         x_star = self.minimizer()
-        g = jax.vmap(lambda A, b: A @ x_star - b)(self.A, self.b)
+        g = jax.vmap(lambda A, b: mm(A, x_star) - b)(self.A, self.b)
         return jnp.mean(jnp.sum(g * g, axis=-1))
 
 

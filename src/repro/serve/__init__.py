@@ -17,7 +17,6 @@ Two layers over the SAME single-round bodies the scan substrates execute
 
 Not to be confused with `repro.launch.serve`, the model-decode batch server.
 """
-from repro.serve.donation import donate_argnums_for
 from repro.serve.pool import SessionPool
 from repro.serve.server import ClientStream, FedRoundServer
 from repro.serve.session import FedSession, open_session, trial_step_def
@@ -30,7 +29,6 @@ __all__ = [
     "PipelinedReadback",
     "ServeStats",
     "SessionPool",
-    "donate_argnums_for",
     "open_session",
     "trial_step_def",
 ]

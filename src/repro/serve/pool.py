@@ -63,7 +63,6 @@ from repro.experiments.runner import (
     pool_entry_signature,
 )
 from repro.experiments.spec import _POOL_HORIZON_KEYS, as_runspec
-from repro.serve.donation import donate_argnums_for
 from repro.serve.session import _REGISTRY_BINDING, FedSession, batched_scan_body
 
 
@@ -155,11 +154,7 @@ def _pool_chunk_fn(algo: str, pool_static_items: tuple):
         comm = jnp.where(active[:, None, None], comm, jnp.zeros_like(comm))
         return new_state, new_cursors, (d2, comm)
 
-    return jax.jit(
-        chunk,
-        static_argnums=0,
-        donate_argnums=donate_argnums_for(jax.default_backend(), 5, 7),
-    )
+    return jax.jit(chunk, static_argnums=0, donate_argnums=(5, 7))
 
 
 @dataclasses.dataclass

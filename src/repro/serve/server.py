@@ -44,7 +44,6 @@ from repro.core.channel import wire_vector_bytes
 from repro.core.flops import flops_at, round_model
 from repro.core.rounds import ROUND_DEFS, make_registry_ops
 from repro.experiments.spec import ALGOS, _REQUIRED
-from repro.serve.donation import donate_argnums_for
 from repro.serve.stats import PipelinedReadback, ServeStats
 
 
@@ -228,11 +227,14 @@ class FedRoundServer:
         def _round(state, key, mask):
             return self._rdef.round(_ops(mask), state, key)
 
-        self._round_fn = jax.jit(
-            _round, donate_argnums=donate_argnums_for(jax.default_backend(), 0)
-        )
+        self._round_fn = jax.jit(_round, donate_argnums=0)
         # Init is sampling-free (anchor setup / comm0), so a full mask is fine.
-        self._state = self._rdef.init(_ops(jnp.ones(M, dtype=bool)), self._x0)
+        # It runs eagerly and may return x0 itself, or one array in two
+        # leaves; the round fn donates its state, so every leaf gets its own
+        # buffer.
+        self._state = jax.tree.map(
+            jnp.copy, self._rdef.init(_ops(jnp.ones(M, dtype=bool)), self._x0)
+        )
         self._base_key = jax.random.key(seed)
         self._round_idx = 0
         self._depth = pipeline_depth

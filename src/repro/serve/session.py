@@ -31,8 +31,7 @@ round body the session steps".  Three substrates (docs/ARCHITECTURE.md):
   replicated so `step()`/`x()`/`result()` behave identically.
 
 State stays on device between `step()` calls and is donated back to each
-chunk (where the backend supports donation), so incremental stepping costs
-one dispatch per chunk, not per round.  The PRNG schedule is the one place
+chunk, so incremental stepping costs one dispatch per chunk, not per round.  The PRNG schedule is the one place
 incrementality needs care: `jax.random.split(key, n)` is NOT prefix-stable
 in `n`, so the session materializes the FULL key schedule for the configured
 horizon at open time and refuses to step past it.
@@ -62,7 +61,6 @@ from repro.core.composite import composite_step_def
 from repro.core.rounds import ROUND_DEFS, client_sharded_step_def, registry_step_def
 from repro.core.types import StepDef
 from repro.experiments.runner import BatchResult, ledger_bytes
-from repro.serve.donation import donate_argnums_for
 from repro.experiments.spec import (
     RunSpec,
     _device_hparams,
@@ -79,13 +77,12 @@ _REGISTRY_BINDING = (
     "channel",
 )
 
-# The chunk fns' state argument positions, gated through the ONE serve-level
-# donation policy (serve/donation.py — CPU ignores donation, so it is only
-# requested where it is real).
-_DONATE_STATE = donate_argnums_for(jax.default_backend(), 4)
-# The client-sharded chunk has two extra leading args (padded problem, valid
-# mask), so its state sits at a different position.
-_DONATE_STATE_CLIENTS = donate_argnums_for(jax.default_backend(), 5)
+# The chunk fns donate their state argument (position 4; 5 for the
+# client-sharded chunk, which takes the padded problem and valid mask first):
+# every backend of the installed jax honours donation, CPU included, so the
+# CPU suite runs the same buffer lifecycle as the chip.  A state handed to a
+# chunk must therefore own its buffers (no leaf shared with another leaf or
+# with x0) and is never read again after the call.
 
 # Post-round state dtype signatures, keyed on the full config+shape signature
 # (see FedSession._canonicalize).
@@ -171,7 +168,7 @@ def _seq_chunk_fn(algo: str, static_items: tuple):
         sd = trial_step_def(algo, problem, x0, x_star, hp, cfg)
         return jax.lax.scan(sd.step, state, keys)
 
-    return jax.jit(chunk, donate_argnums=_DONATE_STATE)
+    return jax.jit(chunk, donate_argnums=4)
 
 
 def batched_scan_body(algo: str, static_items: tuple):
@@ -223,7 +220,7 @@ def _batched_chunk_fn(algo: str, static_items: tuple):
         )
         return fin, (jnp.swapaxes(d2, 0, 1), jnp.swapaxes(comm, 0, 1))
 
-    return jax.jit(chunk, donate_argnums=_DONATE_STATE)
+    return jax.jit(chunk, donate_argnums=4)
 
 
 @functools.lru_cache(maxsize=None)
@@ -357,7 +354,7 @@ def _client_chunk_fn(algo: str, static_items: tuple, num_clients: int, treedef):
         return fin, (jnp.swapaxes(d2, 0, 1), jnp.swapaxes(comm, 0, 1))
 
     mapped = _client_shard_map(local_block, treedef, n_state_specs=5)
-    return jax.jit(mapped, donate_argnums=_DONATE_STATE_CLIENTS)
+    return jax.jit(mapped, donate_argnums=5)
 
 
 @functools.lru_cache(maxsize=None)

@@ -23,7 +23,7 @@ parser on handwritten HLO snippets; `tests/test_roofline.py` holds it against
 real jitted programs.
 
 The peak half is the gpu-recipes `MAX_TFLOPS` idiom grown one step: datasheet
-peaks for accelerators, and a MEASURED peak for CPU (`calibrated_cpu_peak`:
+peaks per accelerator `device_kind`, and a MEASURED peak for CPU (`calibrated_cpu_peak`:
 time a dense matmul on this host, cache the result) — so the CPU MFU numbers
 the bench gate holds are fractions of what this machine demonstrably does,
 not of a made-up constant (docs/PERFORMANCE.md#per-backend-peaks).
@@ -181,13 +181,15 @@ class BackendPeak:
     source: str  # "datasheet" or the calibration recipe used
 
 
-# Datasheet peaks (the gpu-recipes MAX_TFLOPS idiom).  The TPU row matches
-# the dry-run brief's v5e constants above; the GPU row is H100 SXM bf16
-# (SNIPPETS.md's compute_mfu reference point).  CPU has NO datasheet row on
-# purpose: `get_peak("cpu")` measures this host instead.
+# Datasheet peaks, keyed by `jax.Device.device_kind` — a generation, not a
+# platform, so a chip missing from the table raises instead of borrowing
+# another generation's numbers.  CPU has NO row on purpose: `get_peak` on a
+# CPU device measures this host instead (`calibrated_cpu_peak`).
 PEAKS: dict[str, BackendPeak] = {
-    "tpu": BackendPeak(PEAK_FLOPS, HBM_BW, ICI_BW, "datasheet (TPU v5e, bf16)"),
-    "gpu": BackendPeak(989e12, 3350e9, 900e9, "datasheet (H100 SXM, bf16)"),
+    "TPU v5 lite": BackendPeak(
+        PEAK_FLOPS, HBM_BW, ICI_BW,
+        'Google Cloud "TPU v5e" datasheet: 197 TFLOP/s bf16, 819 GB/s HBM',
+    ),
 }
 
 _CPU_PEAK_CACHE: dict[str, BackendPeak] = {}
@@ -225,30 +227,32 @@ def calibrated_cpu_peak(dtype: str = "float32", n: int = 512, reps: int = 5) -> 
     return _CPU_PEAK_CACHE[key]
 
 
-def get_peak(platform: str | None = None, dtype: str = "float32") -> BackendPeak:
-    """The roofline ceiling for `platform` (default: the default jax backend).
+def get_peak(device_kind: str | None = None, dtype: str = "float32") -> BackendPeak:
+    """The roofline ceiling for `device_kind` (default: `jax.devices()[0]`'s).
 
-    Accelerators come from the datasheet table; CPU is measured on first use
+    Accelerators come from the datasheet table `PEAKS` and an unknown kind
+    raises; a CPU device (kind ``"cpu"``) is measured on first use
     (`calibrated_cpu_peak`) and cached for the process.
     """
-    if platform is None:
+    if device_kind is None:
         import jax
 
-        platform = jax.devices()[0].platform
-    if platform in PEAKS:
-        return PEAKS[platform]
-    if platform == "cpu":
+        device_kind = jax.devices()[0].device_kind
+    if device_kind in PEAKS:
+        return PEAKS[device_kind]
+    if device_kind == "cpu":
         return calibrated_cpu_peak(dtype=dtype)
     raise ValueError(
-        f"no peak entry for platform {platform!r}: add it to "
-        "repro.utils.roofline.PEAKS (docs/PERFORMANCE.md#per-backend-peaks)"
+        f"no peak entry for device kind {device_kind!r}: add it, with its "
+        "source, to repro.utils.roofline.PEAKS "
+        "(docs/PERFORMANCE.md#per-backend-peaks)"
     )
 
 
-def mfu(achieved_flops_per_s: float, platform: str | None = None,
+def mfu(achieved_flops_per_s: float, device_kind: str | None = None,
         dtype: str = "float32") -> float:
-    """Model FLOPs utilization: achieved FLOP/s over the backend peak."""
-    return achieved_flops_per_s / get_peak(platform, dtype=dtype).flops
+    """Model FLOPs utilization: achieved FLOP/s over the device's peak."""
+    return achieved_flops_per_s / get_peak(device_kind, dtype=dtype).flops
 
 
 # --------------------------------------------------------------------------
@@ -315,7 +319,4 @@ def xla_flops(fn, *args) -> float:
     import jax
 
     compiled = jax.jit(fn).lower(*args).compile()
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # older jax: one properties dict per device
-        ca = ca[0] if ca else {}
-    return float(ca.get("flops", 0.0))
+    return float(compiled.cost_analysis().get("flops", 0.0))

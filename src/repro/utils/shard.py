@@ -18,42 +18,17 @@ from jax.sharding import PartitionSpec as P
 
 
 def shard_map_compat(f, *, mesh, in_specs, out_specs, manual_axes):
-    """jax.shard_map across jax versions.
-
-    jax >= 0.6 exposes `jax.shard_map(..., axis_names=manual, check_vma=...)`;
-    older releases spell it `jax.experimental.shard_map.shard_map(...,
-    auto=non_manual, check_rep=...)`.  Shared by the launch layer's mesh steps
-    and the experiment engine's `shard="data"` sweep mode.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            axis_names=set(manual_axes), check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    auto = frozenset(mesh.axis_names) - set(manual_axes)
-    return _shard_map(
+    """`jax.shard_map` manual over `manual_axes` (the rest stay auto), with
+    the replication check off.  Shared by the launch layer's mesh steps and
+    the experiment engine's `shard="data"` / `shard="clients"` modes."""
+    return jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False, auto=auto,
+        axis_names=set(manual_axes), check_vma=False,
     )
 
 
 def _active_axes() -> tuple:
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        axes = tuple(getattr(am, "axis_names", ()) or ())
-        if axes:  # empty → fall through: the mesh may be set via `with mesh:`
-            return axes
-    except Exception:
-        pass
-    try:  # jax < 0.5: the `with mesh:` resource env
-        from jax._src.mesh import thread_resources
-
-        m = thread_resources.env.physical_mesh
-        return tuple(m.axis_names) if m.devices.size else ()
-    except Exception:
-        return ()
+    return tuple(jax.sharding.get_abstract_mesh().axis_names)
 
 
 def constrain(x, *spec):

@@ -166,3 +166,21 @@ def test_quant8_is_deterministic_and_prng_free():
     ch = get_channel("quant8")
     v = jax.random.normal(jax.random.key(2), (777,))
     np.testing.assert_array_equal(np.asarray(ch.up(v)), np.asarray(ch.up(v)))
+
+
+def test_quant8_long_rows_go_one_at_a_time_with_identical_numerics():
+    """Rows of ROW_AT_A_TIME_MIN elements or more take the `lax.map` path;
+    it must equal the batched blockwise codec bit for bit, and short rows
+    must keep the batched form (no loop in their program)."""
+    from repro.core import channel as C
+
+    d = C.ROW_AT_A_TIME_MIN + 5  # not a whole number of blocks: the pad path
+    v = jax.random.normal(jax.random.key(3), (2, 3, d))
+    up = jax.jit(get_channel("quant8").up)
+    np.testing.assert_array_equal(np.asarray(up(v)), np.asarray(jax.jit(C._roundtrip_blocks_int8)(v)))
+    assert "scan" in str(jax.make_jaxpr(up)(v))
+    short = v[..., :1000]
+    assert "scan" not in str(jax.make_jaxpr(up)(short))
+    np.testing.assert_array_equal(
+        np.asarray(up(short)), np.stack([np.asarray(up(r)) for r in short.reshape(6, 1000)]).reshape(short.shape)
+    )

@@ -53,7 +53,7 @@ def test_ops_dispatch_pallas_decode():
     k = jax.random.normal(ks[1], (B, S, KVH, Dh))
     v = jax.random.normal(ks[2], (B, S, KVH, Dh))
     valid = jnp.arange(S) <= 20
-    ops.use_pallas(True, interpret=True)
+    ops.use_pallas(True)
     try:
         o_p = ops.decode_attention(q, k, v, valid)
     finally:

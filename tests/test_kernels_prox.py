@@ -164,12 +164,12 @@ def test_prox_update_tree_matches_leafwise(use_pallas):
         lambda yy, gg, zz: ref.prox_update(yy, gg.astype(yy.dtype), zz, 0.1, 2.0), y, g, z
     )
 
-    state = (kops._USE_PALLAS, kops._PALLAS_INTERPRET)
+    state = kops._USE_PALLAS
     try:
-        kops.use_pallas(use_pallas, interpret=True)
+        kops.use_pallas(use_pallas)
         got = kops.prox_update_tree(y, g, z, 0.1, 2.0)
     finally:
-        kops.use_pallas(*state)
+        kops.use_pallas(state)
     for k in y:
         assert got[k].shape == y[k].shape and got[k].dtype == y[k].dtype, k
         tol = dict(atol=2e-2, rtol=2e-2) if y[k].dtype == jnp.bfloat16 else dict(rtol=1e-6)

@@ -7,18 +7,7 @@ import os
 import subprocess
 import sys
 
-import jax
 import pytest
-
-# Partial-auto shard_map (manual client axes + GSPMD-auto 'model' axis) only
-# partitions reliably on the stable `jax.shard_map` of jax >= 0.6; the
-# experimental version in older jaxlibs CHECK-crashes XLA's SPMD partitioner
-# (hlo_sharding_util.cc IsManualSubgroup / spmd_partitioner.cc RET_CHECK) on
-# the embedding-gather jvp.  The pure-data-parallel tests below still run.
-_partial_auto = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="partial-auto shard_map needs jax>=0.6 (old jaxlib SPMD partitioner crashes)",
-)
 
 _ENV_CODE = r"""
 import jax, jax.numpy as jnp, dataclasses, numpy as np
@@ -64,7 +53,6 @@ print('OK')
     assert "OK" in out
 
 
-@_partial_auto
 def test_svrp_train_step_trains_and_schedules_collectives():
     out = _run(
         """
@@ -127,7 +115,6 @@ print('OK')
     assert "OK" in out
 
 
-@_partial_auto
 def test_multipod_mesh_lowering():
     """The 'pod' axis must shard: SVRP step lowers on a (2,2,2) pod mesh."""
     out = _run(

@@ -19,14 +19,15 @@ deliberately uneven chunks.  On top of that contract:
 * mixed-horizon stepping raises the session's past-horizon error per tenant;
 * admission validation (shared `RunSpec` path + `check_pool_entry`) rejects
   un-poolable tenants field by field;
-* the serve-level donation policy (`donate_argnums_for`) is unit-tested per
-  backend string;
+* every state-carrying dispatch (session, pool, server) really donates its
+  input state, so the CPU suite runs the chip's buffer lifecycle;
 * `FedRoundServer(pool=...)` multiplexes tenants with pipelined readback.
 
 A new ALGOS entry fails `test_every_algo_has_a_pool_case` until wired in.
 """
 import copy
 
+import jax
 import numpy as np
 import pytest
 
@@ -43,7 +44,6 @@ from repro.problems import make_synthetic_quadratic
 from repro.serve import (
     FedRoundServer,
     SessionPool,
-    donate_argnums_for,
     open_session,
 )
 
@@ -341,25 +341,34 @@ def test_pool_entry_signature_roundtrip(prob, prob2):
 
 
 # ---------------------------------------------------------------------------
-# Donation gating: ONE serve-level policy, unit-tested per backend string.
+# Donation: every state-carrying dispatch gives up its input state, on every
+# backend (the CPU client of the installed jax honours donation too).
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("backend,expected", [
-    ("cpu", ()),          # CPU ignores donation: never request it
-    ("gpu", (4,)),        # accelerator backends donate the state arg
-    ("cuda", (4,)),
-    ("rocm", (4,)),
-    ("tpu", (4,)),
-    ("unknown_future", (4,)),  # unknown backends default to donating
-])
-def test_donate_argnums_for_backend(backend, expected):
-    assert donate_argnums_for(backend, 4) == expected
+def _step_and_old_state(kind, prob):
+    """Run one dispatch of `kind`; return (state leaves before it, result)."""
+    kw = dict(grid={"eta": 1e-2, "p": 0.1}, seeds=SEEDS, num_steps=6)
+    if kind in ("batched", "sequential", "clients"):
+        sess = open_session("svrp", prob, substrate=kind, **kw)
+        before = jax.tree.leaves(sess._state)
+        sess.step(2)
+        return before, sess.step(2)[0]
+    if kind == "pool":
+        pool = SessionPool(capacity=2)
+        pool.admit("svrp", prob, **kw)
+        before = jax.tree.leaves(pool._state)
+        pool.step(2)
+        return before, pool.step(2)[0]
+    srv = FedRoundServer("svrp", prob, hparams={"eta": 1e-2, "p": 0.1})
+    before = jax.tree.leaves(srv._state)
+    return before, srv.run(3).dist_sq
 
 
-def test_donate_argnums_for_multiple_positions():
-    assert donate_argnums_for("tpu", 0, 5) == (0, 5)
-    assert donate_argnums_for("cpu", 0, 5) == ()
-    assert donate_argnums_for("tpu") == ()
+@pytest.mark.parametrize("kind", ["batched", "sequential", "clients", "pool", "server"])
+def test_dispatch_donates_state(prob, kind):
+    before, out = _step_and_old_state(kind, prob)
+    assert np.all(np.isfinite(np.asarray(out)))
+    assert all(a.is_deleted() for a in before if isinstance(a, jax.Array))
 
 
 # ---------------------------------------------------------------------------

@@ -82,3 +82,55 @@ def test_a9a_like_problem_basics():
     pr = p.prox(m, x, 0.5)
     stat = p.grad(m, pr) + (pr - x) / 0.5
     assert float(jnp.linalg.norm(stat)) < 1e-8
+
+
+def _dot_precisions(jaxpr):
+    """The `precision` of every dot_general in `jaxpr`, sub-jaxprs included
+    (loop bodies, conds, the Pallas kernel body)."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            yield eqn.params["precision"]
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                if isinstance(sub, ClosedJaxpr):
+                    yield from _dot_precisions(sub.jaxpr)
+                elif isinstance(sub, Jaxpr):
+                    yield from _dot_precisions(sub)
+
+
+def _convex_contractions():
+    from repro.core.prox import prox_newton_cg
+    from repro.kernels.logistic_prox import logistic_prox_gd_batched
+
+    q = make_synthetic_quadratic(num_clients=3, dim=8, mu=1.0, L=10.0, delta=1.0, seed=0)
+    lg = make_a9a_like_problem(num_clients=3, n_per_client=16, n_pool=64, dim=8, nnz_per_row=3)
+    m, x, eta = jnp.asarray(1), jnp.ones(8), jnp.asarray(0.5)
+    return {
+        "quadratic.grad": lambda: q.grad(m, x),
+        "quadratic.full_grad": lambda: q.full_grad(x),
+        "quadratic.local_oracle": lambda: q.local_oracle(m)[0](x),
+        "quadratic.prox_spectral": lambda: q.prox_spectral(m, x, eta, q.prox_factors()),
+        "logistic.grad": lambda: lg.grad(m, x),
+        "logistic.full_grad": lambda: lg.full_grad(x),
+        "logistic.local_oracle": lambda: [f(x) for f in lg.local_oracle(m)],
+        "logistic.smoothness_max": lambda: lg.smoothness_max(),
+        "logistic.minimizer": lambda: lg.minimizer(steps=3),
+        "prox_newton_cg": lambda: prox_newton_cg(lg.local_oracle(m)[0], x, eta, max_steps=2),
+        "logistic_prox_kernel": lambda: logistic_prox_gd_batched(
+            jnp.ones((2, 16, 8)), jnp.ones((2, 8)), jnp.ones(2), jnp.ones(2), 0.1, 2),
+    }
+
+
+@pytest.mark.parametrize("name", sorted(_convex_contractions()))
+def test_convex_contractions_are_highest_precision(name):
+    """A TPU's default float32 matmul rounds operands to bfloat16; the convex
+    oracles, solvers and the Algorithm-7 kernel ask for full precision."""
+    import jax
+
+    from repro.core.precision import HIGHEST
+
+    precisions = list(_dot_precisions(jax.make_jaxpr(_convex_contractions()[name])().jaxpr))
+    assert precisions, name
+    assert all(p == (HIGHEST, HIGHEST) for p in precisions), (name, precisions)

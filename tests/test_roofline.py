@@ -46,11 +46,8 @@ import sys
 sys.path.insert(0, "src")
 from repro.launch import roofline as rl
 
-try:  # jax >= 0.5
-    from jax.sharding import AxisType
-    mesh = jax.make_mesh((4,), ("data",), axis_types=(AxisType.Auto,))
-except ImportError:
-    mesh = jax.make_mesh((4,), ("data",))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((4,), ("data",), axis_types=(AxisType.Auto,))
 
 def f(x):
     def body(x, _):
@@ -58,12 +55,8 @@ def f(x):
     x, _ = jax.lax.scan(body, x, None, length=5)
     return x
 
-if hasattr(jax, "shard_map"):  # jax >= 0.6
-    sm = jax.shard_map(f, mesh=mesh, in_specs=(P(),), out_specs=P(),
-                       axis_names={"data"}, check_vma=False)
-else:
-    from jax.experimental.shard_map import shard_map
-    sm = shard_map(f, mesh=mesh, in_specs=(P(),), out_specs=P(), check_rep=False)
+sm = jax.shard_map(f, mesh=mesh, in_specs=(P(),), out_specs=P(),
+                   axis_names={"data"}, check_vma=False)
 c = jax.jit(sm).lower(jnp.zeros((8, 128))).compile()
 txt = c.as_text()
 by, counts = rl.collective_stats(txt)
