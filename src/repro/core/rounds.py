@@ -85,6 +85,7 @@ import jax.numpy as jnp
 
 from repro.core.channel import get_channel
 from repro.core.types import RunResult
+from repro.utils.spans import scope
 
 
 class RoundDef(NamedTuple):
@@ -93,6 +94,24 @@ class RoundDef(NamedTuple):
     name: str
     init: Callable  # (ops, x0) -> state
     round: Callable  # (ops, state, key) -> (state, (dist_sq, comm))
+
+
+class _ScopedHook:
+    """A substrate hook injected as a callable (the local prox solves): on
+    assignment it is wrapped in its named scope, so every binding, and every
+    later reassignment, runs under the same name."""
+
+    def __init__(self, scope_name: str):
+        self.scope_name = scope_name
+
+    def __set_name__(self, owner, name):
+        self.attr = "_" + name
+
+    def __get__(self, obj, owner=None):
+        return self if obj is None else getattr(obj, self.attr)
+
+    def __set__(self, obj, fn):
+        setattr(obj, self.attr, None if fn is None else scope(self.scope_name)(fn))
 
 
 class RoundOps:
@@ -107,7 +126,17 @@ class RoundOps:
     caller: ``prox(m, z)`` for single-client rounds (sppm/svrp),
     ``cohort_prox(ms, z)`` for minibatch cohorts, ``local_prox_gd(z, y0)`` for
     DeepSVRP's explicit-stepsize Algorithm-7 loop.
+
+    Each hook runs under a named scope (`repro.utils.spans`), which marks the
+    device operations it emits in the HLO metadata and the profiler's trace:
+    ``sample``, ``grad``, ``prox``, ``channel``, ``refresh``,
+    ``client_mean``, ``psum`` (client-sharded collectives) and
+    ``accounting`` (``dist_sq`` and the round bodies' comm count).
     """
+
+    prox = _ScopedHook("prox")
+    cohort_prox = _ScopedHook("prox")
+    local_prox_gd = _ScopedHook("prox")
 
     def __init__(
         self,
@@ -153,7 +182,7 @@ class RoundOps:
         self._full_grad = problem.full_grad
         self.oracle_overridden = grad is not None or full_grad is not None
         if grad is not None:
-            self.grad = grad
+            self.grad = scope("grad")(grad)
         if full_grad is not None:
             self.full_grad = full_grad
 
@@ -174,6 +203,7 @@ class RoundOps:
         s = jax.vmap(jax.random.split)(key)  # (B, 2) keys
         return s[:, 0], s[:, 1]
 
+    @scope("sample")
     def uniform_client(self, key):
         if self._uniform_client_fn is not None:
             return self._uniform_client_fn(key)
@@ -181,6 +211,7 @@ class RoundOps:
             return jax.random.randint(key, (), 0, self.M)
         return jax.vmap(lambda k: jax.random.randint(k, (), 0, self.M))(key)
 
+    @scope("sample")
     def sample_cohort(self, key):
         """``cohort_size`` clients without replacement (minibatch SVRP)."""
         if self._sample_cohort_fn is not None:
@@ -192,6 +223,7 @@ class RoundOps:
             lambda k: jax.random.choice(k, self.M, shape=(b,), replace=False)
         )(key)
 
+    @scope("sample")
     def bernoulli(self, key, p):
         p = jnp.asarray(p, self.dtype)
         if not self.batched:
@@ -199,6 +231,7 @@ class RoundOps:
         return jax.vmap(jax.random.bernoulli)(key, jnp.broadcast_to(p, (self.B,)))
 
     # ------------------------------------------------------------- oracles
+    @scope("grad")
     def grad(self, m, y):
         if not self.batched:
             return self._grad(m, y)
@@ -209,6 +242,7 @@ class RoundOps:
             return self._full_grad(w)
         return jax.vmap(self._full_grad)(w)
 
+    @scope("grad")
     def cohort_grad(self, ms, y):
         """Per-cohort-client gradients at the shared iterate: (b, d) / (B, b, d).
 
@@ -228,6 +262,7 @@ class RoundOps:
             return jax.vmap(per_trial, in_axes=(None, 0))(ms, y)
         return jax.vmap(per_trial)(ms, y)
 
+    @scope("refresh")
     def init_full_grad(self, x0):
         """Round-0 anchor gradient for a trial-SHARED ``x0``: computed once on
         the raw problem oracle and tiled to per-trial state.  A substrate
@@ -237,6 +272,7 @@ class RoundOps:
         refresh events."""
         return self.tile(self._full_grad(x0))
 
+    @scope("refresh")
     def refresh_grad(self, c, w_next, gbar):
         """Anchor-gradient refresh.  Sequential: the historical lazy
         ``lax.cond`` (full gradient paid only on refresh steps).  Batched: the
@@ -297,28 +333,33 @@ class RoundOps:
         broadcast payload.  Replicated per-trial state on every substrate."""
         return self.channel.init_state(xB)
 
+    @scope("channel")
     def chan_down(self, ch, x):
         """Server -> client iterate broadcast — the one stateful link: the
         quant8 channel transmits ``Q(x + e)`` and carries the residual."""
         return self.channel.down(ch, x)
 
+    @scope("channel")
     def chan_up(self, v):
         """Client -> server payloads (prox results), stateless, compressed
         row-independently along the last axis."""
         return self.channel.up(v)
 
+    @scope("channel")
     def chan_bcast(self, v):
         """Anchor broadcast on refresh events, stateless: clients store the
         anchor AS RECEIVED, so the cached anchor gradient stays consistent
         with the anchor the clients actually hold."""
         return self.channel.bcast(v)
 
+    @scope("client_mean")
     def client_mean(self, y):
         """Mean over the client axis of full-participation rows (DeepSVRP).
         A substrate primitive so the client-sharded binding can assemble the
         GLOBAL mean from resident rows with its one masked ``psum``."""
         return jnp.mean(y, axis=-2)
 
+    @scope("accounting")
     def dist_sq(self, x):
         metric = getattr(self.problem, "metric", None)
         if metric is not None:
@@ -375,7 +416,8 @@ def _sppm_round(ops: RoundOps, s, key_k):
     m = ops.uniform_client(key_k)
     ch, x_d = ops.chan_down(ch, x)
     x_next = ops.chan_up(ops.prox(m, x_d))
-    comm = comm + 2  # server -> client (x_k), client -> server (x_{k+1})
+    with scope("accounting"):
+        comm = comm + 2  # server -> client (x_k), client -> server (x_{k+1})
     return (x_next, comm, ch), (ops.dist_sq(x_next), comm)
 
 
@@ -402,7 +444,8 @@ def _svrp_round(ops: RoundOps, s, key_k):
     c = ops.bernoulli(key_c, ops.hp.p)
     w_next = ops.where_vec(c, ops.chan_bcast(x_next), w)
     gbar_next = ops.refresh_grad(c, w_next, gbar)
-    comm = comm + 2 + 3 * ops.M * ops.as_count(c)
+    with scope("accounting"):
+        comm = comm + 2 + 3 * ops.M * ops.as_count(c)
     return (x_next, w_next, gbar_next, comm, ch), (ops.dist_sq(x_next), comm)
 
 
@@ -420,7 +463,8 @@ def _svrp_minibatch_round(ops: RoundOps, s, key_k):
     c = ops.bernoulli(key_c, ops.hp.p)
     w_next = ops.where_vec(c, ops.chan_bcast(x_next), w)
     gbar_next = ops.refresh_grad(c, w_next, gbar)
-    comm = comm + 2 * ops.cohort_size + 3 * ops.M * ops.as_count(c)
+    with scope("accounting"):
+        comm = comm + 2 * ops.cohort_size + 3 * ops.M * ops.as_count(c)
     return (x_next, w_next, gbar_next, comm, ch), (ops.dist_sq(x_next), comm)
 
 
@@ -442,7 +486,8 @@ def _deep_svrp_round(ops: RoundOps, s, key_k):
     gbar_next = ops.refresh_grad(c, w_next, gbar)
     # Full participation: 2M per round (x down / y up for all cohorts) + a
     # Bernoulli-gated 2M for the anchor-gradient all-reduce.
-    comm = comm + 2 * ops.M + 2 * ops.M * ops.as_count(c)
+    with scope("accounting"):
+        comm = comm + 2 * ops.M + 2 * ops.M * ops.as_count(c)
     return (x_next, w_next, gbar_next, comm, ch), (ops.dist_sq(x_next), comm)
 
 
@@ -1004,22 +1049,27 @@ class ClientShardedOps(RoundOps):
         resident = (local >= 0) & (local < self.M_local)
         return jnp.clip(local, 0, self.M_local - 1), resident
 
+    @scope("psum")
+    def psum(self, value):
+        """The substrate's one collective: an all-reduce over the client axis."""
+        return jax.lax.psum(value, self.axis)
+
+    @scope("psum")
     def masked_psum(self, value, resident):
         """Assemble owner-computed rows: zeros elsewhere make the all-reduce
         exact.  ``resident`` broadcasts against ``value``'s leading axes."""
         resident = jnp.expand_dims(resident, -1)
-        return jax.lax.psum(
-            jnp.where(resident, value, jnp.zeros_like(value)), self.axis
-        )
+        return self.psum(jnp.where(resident, value, jnp.zeros_like(value)))
 
     def mean_clients(self, y):
         """(B, M_local, d) resident rows -> the GLOBAL client mean broadcast
         back over the local block (so round bodies' ``jnp.mean(axis=-2)``
         reproduces the unsharded mean).  One ``psum``."""
         s = jnp.sum(jnp.where(self.valid[None, :, None], y, 0.0), axis=1)
-        ybar = jax.lax.psum(s, self.axis) / self.M
+        ybar = self.psum(s) / self.M
         return jnp.broadcast_to(ybar[:, None, :], y.shape)
 
+    @scope("client_mean")
     def client_mean(self, y):
         """DeepSVRP's client mean over RESIDENT rows: masked local sum, the
         round's one ``psum``, divide by the global M.  Channel compression of
@@ -1027,9 +1077,10 @@ class ClientShardedOps(RoundOps):
         independently BEFORE the mean on every substrate, and padding rows
         are masked out of the sum here exactly as in the unsharded mean."""
         s = jnp.sum(jnp.where(self.valid[None, :, None], y, 0.0), axis=1)
-        return jax.lax.psum(s, self.axis) / self.M
+        return self.psum(s) / self.M
 
     # ------------------------------------------------------------- oracles
+    @scope("grad")
     def grad(self, m, y):
         """Owner-masked sampled-client gradient — deliberately NOT psummed:
         it only feeds the same client's prox input, whose result the round's
@@ -1038,6 +1089,7 @@ class ClientShardedOps(RoundOps):
         g = jax.vmap(self._grad)(local, y)
         return jnp.where(resident[:, None], g, jnp.zeros_like(g))
 
+    @scope("grad")
     def cohort_grad(self, ms, y):
         if ms.ndim == 1:
             # Full participation (DeepSVRP): the resident client block.  The
@@ -1058,8 +1110,9 @@ class ClientShardedOps(RoundOps):
         per_trial = jax.vmap(self._grad, in_axes=(0, None))
         rows = jax.vmap(per_trial, in_axes=(None, 0))(local_ids, w)  # (B, M_l, d)
         s = jnp.sum(jnp.where(self.valid[None, :, None], rows, 0.0), axis=1)
-        return jax.lax.psum(s, self.axis) / self.M
+        return self.psum(s) / self.M
 
+    @scope("refresh")
     def init_full_grad(self, x0):
         """The round-0 anchor: same masked-sum + one-psum assembly as the
         refresh events, on the trial-shared ``x0``."""
@@ -1067,7 +1120,7 @@ class ClientShardedOps(RoundOps):
             jnp.arange(self.M_local), x0
         )
         s = jnp.sum(jnp.where(self.valid[:, None], rows, 0.0), axis=0)
-        return self.tile(jax.lax.psum(s, self.axis) / self.M)
+        return self.tile(self.psum(s) / self.M)
 
 
 def make_client_sharded_ops(

@@ -95,6 +95,7 @@ from repro.experiments.spec import (  # noqa: F401  (re-exported API)
     resolve_algo,
 )
 from repro.utils.shard import shard_map_compat
+from repro.utils.spans import next_call, span
 
 
 class BatchResult(NamedTuple):
@@ -316,30 +317,56 @@ def run_batch(
     Per-trial outputs match the sequential `run_<algo>` driver for the same
     (seed, hparams) to float tolerance — see tests/test_experiments.py and
     tests/test_sharded.py.
+
+    Each call is one `repro:run_batch` profiler span (its `call` number,
+    `trials`, `rounds`) holding `run_batch.prepare` (spec, resolve, hparams
+    and keys to the device), `run_batch.dispatch` (the jitted runner: a
+    compile or cache load shows here) and `run_batch.wait` (the comm read the
+    bytes ledger needs, which waits for the device); `repro.utils.spans`.
     """
-    spec_ = as_runspec(algo, grid=grid, seeds=seeds, x0=x0, x_star=x_star,
-                       stepsize=stepsize, target_eps=target_eps,
-                       theory_constants=theory_constants, static=static)
-    rr = spec_.resolve(problem)
-    algo, spec = rr.algo, rr.aspec
-    hparams, seed_arr, cfg, x0, x_star = rr.hparams, rr.seeds, rr.cfg, rr.x0, rr.x_star
+    call = next_call()
+    with span("run_batch", call=call) as outer:
+        with span("run_batch.prepare", call=call):
+            spec_ = as_runspec(algo, grid=grid, seeds=seeds, x0=x0, x_star=x_star,
+                               stepsize=stepsize, target_eps=target_eps,
+                               theory_constants=theory_constants, static=static)
+            rr = spec_.resolve(problem)
+            if stop_eps is None:
+                hp, keys = rr.device_hparams(), rr.keys()
+        if stop_eps is not None:
+            if fused or shard is not None or interpret is not None or devices is not None:
+                raise ValueError(
+                    "stop_eps runs on the incremental session substrate; it cannot "
+                    "be combined with fused=, interpret=, shard= or devices="
+                )
+            import dataclasses
 
-    if stop_eps is not None:
-        if fused or shard is not None or interpret is not None or devices is not None:
-            raise ValueError(
-                "stop_eps runs on the incremental session substrate; it cannot "
-                "be combined with fused=, interpret=, shard= or devices="
+            from repro.serve import open_session  # lazy: serve imports this module
+
+            sess = open_session(dataclasses.replace(spec_, substrate="batched"), problem)
+            out = sess.run_until(stop_eps)
+        else:
+            res = _dispatch(call, rr, problem, keys, hp, fused=fused,
+                            interpret=interpret, shard=shard, devices=devices)
+            with span("run_batch.wait", call=call):
+                comm_bytes = ledger_bytes(rr.cfg, rr.x0, res.comm)
+            out = BatchResult(
+                dist_sq=res.dist_sq,
+                comm=res.comm,
+                x_final=res.x_final,
+                hparams=rr.hparams,
+                seeds=rr.seeds,
+                comm_bytes=comm_bytes,
             )
-        import dataclasses
+        outer.set_metadata(trials=out.num_trials, rounds=out.dist_sq.shape[-1])
+        return out
 
-        from repro.serve import open_session  # lazy: serve imports this module
 
-        sess = open_session(dataclasses.replace(spec_, substrate="batched"), problem)
-        return sess.run_until(stop_eps)
-
-    hp = spec.params_cls(**_device_hparams(hparams))
-    keys = _keys_for(seed_arr)
-
+def _dispatch(call: int, rr: ResolvedRun, problem, keys, hp, *, fused, interpret,
+              shard, devices) -> RunResult:
+    """Pick `run_batch`'s substrate for a resolved run and launch it (no
+    wait: the result's arrays are still being computed)."""
+    algo, spec, cfg, x0, x_star = rr.algo, rr.aspec, rr.cfg, rr.x0, rr.x_star
     if shard not in (None, "data", "clients"):
         raise ValueError(
             f"unknown shard mode {shard!r}; supported: 'data', 'clients'"
@@ -366,18 +393,11 @@ def run_batch(
             fused_oracle_kind(problem)
         elif interpret is not None:
             raise ValueError("interpret only applies to the fused=True Pallas path")
-        res = _run_client_sharded(
-            algo, tuple(sorted(cfg.items())), problem, x0, x_star, keys, hp,
-            devices=devices, fused=fused, interpret=interpret,
-        )
-        return BatchResult(
-            dist_sq=res.dist_sq,
-            comm=res.comm,
-            x_final=res.x_final,
-            hparams=hparams,
-            seeds=seed_arr,
-            comm_bytes=ledger_bytes(cfg, x0, res.comm),
-        )
+        with span("run_batch.dispatch", call=call):
+            return _run_client_sharded(
+                algo, tuple(sorted(cfg.items())), problem, x0, x_star, keys, hp,
+                devices=devices, fused=fused, interpret=interpret,
+            )
     if fused:
         # Registry-prox algos fuse only their "gd" path; deep_svrp's local
         # solver IS Algorithm 7, so it has no prox_solver switch to check.
@@ -400,19 +420,10 @@ def run_batch(
             body = _vmapped_trials(spec.scan_fn, tuple(sorted(cfg.items())))
             runner = _batched_runner(spec.scan_fn, tuple(sorted(cfg.items())))
 
-    if shard is None:
-        res = runner(problem, x0, x_star, keys, hp)
-    else:
-        res = _run_sharded(body, problem, x0, x_star, keys, hp, devices)
-
-    return BatchResult(
-        dist_sq=res.dist_sq,
-        comm=res.comm,
-        x_final=res.x_final,
-        hparams=hparams,
-        seeds=seed_arr,
-        comm_bytes=ledger_bytes(cfg, x0, res.comm),
-    )
+    with span("run_batch.dispatch", call=call):
+        if shard is None:
+            return runner(problem, x0, x_star, keys, hp)
+        return _run_sharded(body, problem, x0, x_star, keys, hp, devices)
 
 
 def run_sequential(

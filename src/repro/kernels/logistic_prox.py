@@ -123,5 +123,6 @@ def logistic_prox_gd_batched(
         out_specs=row,
         out_shape=jax.ShapeDtypeStruct((B, 1, d_pad), dtype),
         interpret=resolve_interpret(interpret),
+        name="logistic_prox_gd_batched",
     )(scalars, A_p, z_p, x0_p)
     return out[:, 0, :d]

@@ -140,5 +140,6 @@ def prox_update_batched(y, g, z, local_lr, inv_eta, *, interpret: bool | None = 
         out_specs=pl.BlockSpec((1, block_rows, cols), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct(yp.shape, dtype),
         interpret=resolve_interpret(interpret),
+        name="prox_update_batched",
     )(scalars, yp, gp, zp)
     return out[:, :rows_total].reshape(B, -1)[:, :n].reshape(shape)

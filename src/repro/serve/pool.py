@@ -64,6 +64,7 @@ from repro.experiments.runner import (
 )
 from repro.experiments.spec import _POOL_HORIZON_KEYS, as_runspec
 from repro.serve.session import _REGISTRY_BINDING, FedSession, batched_scan_body
+from repro.utils.spans import span
 
 
 def _is_key_dtype(a) -> bool:
@@ -223,6 +224,7 @@ class SessionPool:
         self._cursors_dev = None
         self._active_dev = None
         self._lanes_dirty = True
+        self._tick = 0  # steps taken: the `tick` argument of the pool's spans
 
     # ------------------------------------------------------------- admission
     def admit(
@@ -245,47 +247,48 @@ class SessionPool:
         Mid-run admission is safe by construction: the new tenant's key
         schedule is its own (materialized at open, starting at round 0), and
         existing lanes' state is untouched."""
-        spec = as_runspec(
-            algo, grid=grid, seeds=seeds, x0=x0, x_star=x_star,
-            stepsize=stepsize, target_eps=target_eps,
-            theory_constants=theory_constants, substrate=None, static=static,
-        )
-        if spec.substrate not in (None, "batched"):
-            raise ValueError(
-                f"SessionPool packs the batched substrate only; "
-                f"got substrate={spec.substrate!r}"
+        with span("pool.admit", tick=self._tick):
+            spec = as_runspec(
+                algo, grid=grid, seeds=seeds, x0=x0, x_star=x_star,
+                stepsize=stepsize, target_eps=target_eps,
+                theory_constants=theory_constants, substrate=None, static=static,
             )
-        spec = dataclasses.replace(spec, substrate="batched")
-        session = FedSession(spec, problem)
-        sig = pool_entry_signature(
-            session._algo, session._cfg, session._B,
-            session._problem, session._x0, session._x_star,
-        )
-        if self._signature is None:
-            self._install_signature(sig, session)
-        else:
-            check_pool_entry(self._signature, sig)
-        slot = next(
-            (i for i, t in enumerate(self._slots) if t is None), None
-        )
-        if slot is None:
-            raise ValueError(
-                f"pool is full ({self.capacity} slots); evict a tenant first"
+            if spec.substrate not in (None, "batched"):
+                raise ValueError(
+                    f"SessionPool packs the batched substrate only; "
+                    f"got substrate={spec.substrate!r}"
+                )
+            spec = dataclasses.replace(spec, substrate="batched")
+            session = FedSession(spec, problem)
+            sig = pool_entry_signature(
+                session._algo, session._cfg, session._B,
+                session._problem, session._x0, session._x_star,
             )
-        tenant = PoolTenant(
-            id=self._next_id, slot=slot, session=session, stop_eps=stop_eps
-        )
-        self._next_id += 1
-        self._slots[slot] = tenant
-        self._tenants[tenant.id] = tenant
-        self._problems = _lane_set(self._problems, slot, session._problem)
-        self._x0 = self._x0.at[slot].set(session._x0)
-        self._x_star = self._x_star.at[slot].set(session._x_star)
-        self._hp = _lane_set(self._hp, slot, session._hp)
-        self._state = _lane_set(self._state, slot, session._state)
-        self._write_key_lane(slot, session)
-        self._lanes_dirty = True
-        return tenant.id
+            if self._signature is None:
+                self._install_signature(sig, session)
+            else:
+                check_pool_entry(self._signature, sig)
+            slot = next(
+                (i for i, t in enumerate(self._slots) if t is None), None
+            )
+            if slot is None:
+                raise ValueError(
+                    f"pool is full ({self.capacity} slots); evict a tenant first"
+                )
+            tenant = PoolTenant(
+                id=self._next_id, slot=slot, session=session, stop_eps=stop_eps
+            )
+            self._next_id += 1
+            self._slots[slot] = tenant
+            self._tenants[tenant.id] = tenant
+            self._problems = _lane_set(self._problems, slot, session._problem)
+            self._x0 = self._x0.at[slot].set(session._x0)
+            self._x_star = self._x_star.at[slot].set(session._x_star)
+            self._hp = _lane_set(self._hp, slot, session._hp)
+            self._state = _lane_set(self._state, slot, session._state)
+            self._write_key_lane(slot, session)
+            self._lanes_dirty = True
+            return tenant.id
 
     def _write_key_lane(self, slot: int, session: FedSession) -> None:
         """Copy the tenant's whole key schedule into its buffer lane
@@ -349,51 +352,55 @@ class SessionPool:
         (inactive lanes zero).  Raises the session's past-horizon error,
         prefixed with the offending tenant id, if any running tenant's key
         schedule cannot cover `n` more rounds."""
-        if n < 1:
-            raise ValueError(f"step(n={n}): n must be >= 1")
-        running = [t for t in self._slots if t is not None and t.running]
-        if not running:
-            raise ValueError(
-                "pool has no running tenants — admit() one (or un-freeze via "
-                "evict+admit) before stepping"
-            )
-        for t in running:
-            ses = t.session
-            if ses.t + n > ses.horizon:
+        with span("pool.step", tick=self._tick):
+            if n < 1:
+                raise ValueError(f"step(n={n}): n must be >= 1")
+            running = [t for t in self._slots if t is not None and t.running]
+            if not running:
                 raise ValueError(
-                    f"pool tenant {t.id}: session horizon exhausted: "
-                    f"{ses.t} rounds done, {n} more requested, horizon "
-                    f"{ses.horizon}.  The PRNG key schedule is fixed at open "
-                    "(split is not prefix-stable) — evict the tenant and "
-                    "admit a new session with a larger round budget."
+                    "pool has no running tenants — admit() one (or un-freeze via "
+                    "evict+admit) before stepping"
                 )
-        if self._lanes_dirty:
-            cursors = np.zeros(self.capacity, dtype=np.int32)
-            active = np.zeros(self.capacity, dtype=bool)
-            for slot in range(self.capacity):
-                t = self._slots[slot]
-                if t is not None and t.running:
-                    active[slot] = True
-                    cursors[slot] = t.session.t
-            self._cursors_dev = jnp.asarray(cursors)
-            self._active_dev = jnp.asarray(active)
-            self._lanes_dirty = False
-        chunk = _pool_chunk_fn(self._algo, self._pool_static_items)
-        self._state, self._cursors_dev, (d2, comm) = chunk(
-            n, self._problems, self._x0, self._x_star, self._hp,
-            self._state, self._keys_buf, self._cursors_dev, self._active_dev,
-        )
-        self._blocks.append((d2, comm))
-        idx = self._block_offset + len(self._blocks) - 1
-        for t in running:
-            t.pending.append(idx)
-            t.session._t += n
-            if t.stop_eps is not None:
-                self._drain(t)
-                if t.session._all_reached(t.stop_eps):
-                    t.frozen = True  # lane masked from the next chunk on
-                    self._lanes_dirty = True
-        return d2, comm
+            for t in running:
+                ses = t.session
+                if ses.t + n > ses.horizon:
+                    raise ValueError(
+                        f"pool tenant {t.id}: session horizon exhausted: "
+                        f"{ses.t} rounds done, {n} more requested, horizon "
+                        f"{ses.horizon}.  The PRNG key schedule is fixed at open "
+                        "(split is not prefix-stable) — evict the tenant and "
+                        "admit a new session with a larger round budget."
+                    )
+            if self._lanes_dirty:
+                cursors = np.zeros(self.capacity, dtype=np.int32)
+                active = np.zeros(self.capacity, dtype=bool)
+                for slot in range(self.capacity):
+                    t = self._slots[slot]
+                    if t is not None and t.running:
+                        active[slot] = True
+                        cursors[slot] = t.session.t
+                self._cursors_dev = jnp.asarray(cursors)
+                self._active_dev = jnp.asarray(active)
+                self._lanes_dirty = False
+            chunk = _pool_chunk_fn(self._algo, self._pool_static_items)
+            with span("pool.step.dispatch", tick=self._tick):
+                self._state, self._cursors_dev, (d2, comm) = chunk(
+                    n, self._problems, self._x0, self._x_star, self._hp,
+                    self._state, self._keys_buf, self._cursors_dev, self._active_dev,
+                )
+            self._blocks.append((d2, comm))
+            idx = self._block_offset + len(self._blocks) - 1
+            with span("pool.step.drain", tick=self._tick):
+                for t in running:
+                    t.pending.append(idx)
+                    t.session._t += n
+                    if t.stop_eps is not None:
+                        self._drain(t)
+                        if t.session._all_reached(t.stop_eps):
+                            t.frozen = True  # lane masked from the next chunk on
+                            self._lanes_dirty = True
+            self._tick += 1
+            return d2, comm
 
     def freeze_exhausted(self, n: int = 1) -> int:
         """Freeze every running tenant whose key schedule cannot cover `n`
@@ -415,16 +422,17 @@ class SessionPool:
         """Release a tenant's slot (state written back into its standalone
         `FedSession`, which is returned fully usable); the lane is zeroed and
         contributes nothing until re-admitted."""
-        t = self._require(tenant_id)
-        if t.evicted:
-            raise ValueError(f"tenant {tenant_id} already evicted")
-        self._sync(t)
-        zero = jax.tree.map(lambda a: _zero_lanes(a, 1)[0], t.session._state)
-        self._state = _lane_set(self._state, t.slot, zero)
-        t.evicted = True
-        self._slots[t.slot] = None
-        self._lanes_dirty = True
-        return t.session
+        with span("pool.evict", tick=self._tick):
+            t = self._require(tenant_id)
+            if t.evicted:
+                raise ValueError(f"tenant {tenant_id} already evicted")
+            self._sync(t)
+            zero = jax.tree.map(lambda a: _zero_lanes(a, 1)[0], t.session._state)
+            self._state = _lane_set(self._state, t.slot, zero)
+            t.evicted = True
+            self._slots[t.slot] = None
+            self._lanes_dirty = True
+            return t.session
 
     def session(self, tenant_id: int) -> FedSession:
         """The tenant's `FedSession`, state synced from its pool lane."""

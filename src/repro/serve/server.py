@@ -45,6 +45,7 @@ from repro.core.flops import flops_at, round_model
 from repro.core.rounds import ROUND_DEFS, make_registry_ops
 from repro.experiments.spec import ALGOS, _REQUIRED
 from repro.serve.stats import PipelinedReadback, ServeStats
+from repro.utils.spans import span
 
 
 class ClientStream:
@@ -258,9 +259,10 @@ class FedRoundServer:
         start = time.perf_counter()
 
         def drain_one(t0: float, round_idx: int, d2: Any, comm: Any) -> None:
-            d2_host = float(d2)  # blocks until the round's result is ready
-            now = time.perf_counter()
-            comm_host = int(comm)
+            with span("server.readback", round=round_idx):
+                d2_host = float(d2)  # blocks until the round's result is ready
+                now = time.perf_counter()
+                comm_host = int(comm)
             self.stats.record(
                 now - t0, now - start, d2_host, comm_host,
                 comm_bytes=comm_host * self._wire_bytes,
@@ -272,7 +274,8 @@ class FedRoundServer:
             mask = jnp.asarray(self._stream.tick())
             key_t = jax.random.fold_in(self._base_key, self._round_idx)
             t0 = time.perf_counter()
-            self._state, (d2, comm) = self._round_fn(self._state, key_t, mask)
+            with span("server.dispatch", round=self._round_idx + 1):
+                self._state, (d2, comm) = self._round_fn(self._state, key_t, mask)
             self._round_idx += 1
             readback.push(t0, self._round_idx, d2, comm)
         readback.flush()
@@ -303,11 +306,13 @@ class FedRoundServer:
         flops_served = getattr(self, "_flops_served", 0.0)
         model = getattr(pool, "flops_model", None)
 
-        def drain_one(t0: float, active: np.ndarray, d2: Any, comm: Any) -> None:
+        def drain_one(t0: float, round_idx: int, active: np.ndarray, d2: Any,
+                      comm: Any) -> None:
             nonlocal served, flops_served
-            d2_host = np.asarray(d2)  # blocks until the tick's result is ready
-            now = time.perf_counter()
-            comm_host = np.asarray(comm)  # (P, B, 1) cumulative, masked lanes 0
+            with span("server.readback", round=round_idx):
+                d2_host = np.asarray(d2)  # blocks until the tick's result is ready
+                now = time.perf_counter()
+                comm_host = np.asarray(comm)  # (P, B, 1) cumulative, masked lanes 0
             mean_d2 = float(d2_host[active, :, -1].mean())
             lane_totals = comm_host[:, :, -1].sum(axis=1).astype(np.int64)
             delta = int((lane_totals - base)[active].sum())
@@ -352,9 +357,10 @@ class FedRoundServer:
                 break  # every tenant converged, evicted, or out of horizon
             active = pool.active_mask
             t0 = time.perf_counter()
-            d2, comm = pool.step(1)
+            with span("server.dispatch", round=self._round_idx + 1):
+                d2, comm = pool.step(1)
             self._round_idx += 1
-            readback.push(t0, active, d2, comm)
+            readback.push(t0, self._round_idx, active, d2, comm)
         readback.flush()
         self._comm_served = served
         self._flops_served = flops_served

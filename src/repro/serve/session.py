@@ -68,6 +68,7 @@ from repro.experiments.spec import (
     check_substrate,
     horizon_rounds,
 )
+from repro.utils.spans import span
 
 # Static-config keys that parameterize the registry round binding (subset
 # present per algo: prox trio for the registry-prox algos, cohort size for
@@ -595,33 +596,34 @@ class FedSession:
     def step(self, n: int = 1) -> tuple[jax.Array, jax.Array]:
         """Advance every trial `n` rounds (one jitted chunk); returns the
         `(B, n)` dist-sq and cumulative-comm block for those rounds."""
-        if n < 1:
-            raise ValueError(f"step(n={n}): n must be >= 1")
-        if self._t + n > self._horizon:
-            raise ValueError(
-                f"session horizon exhausted: {self._t} rounds done, {n} more "
-                f"requested, horizon {self._horizon}.  The PRNG key schedule "
-                "is fixed at open (split is not prefix-stable) — open a new "
-                "session with a larger round budget to continue."
-            )
-        sl = slice(self._t, self._t + n)
-        if self._substrate in ("batched", "clients"):
-            self._state, (d2, comm) = self._chunk_call(self._state, self._keys[:, sl])
-        else:
-            chunk = _seq_chunk_fn(self._algo, self._static_items)
-            d2_rows, comm_rows = [], []
-            for i in range(self._B):
-                self._state[i], (d2_i, comm_i) = chunk(
-                    self._problem, self._x0, self._x_star, self._hp_i(i),
-                    self._state[i], self._keys[i, sl],
+        with span("session.step", t=self._t, n=n):
+            if n < 1:
+                raise ValueError(f"step(n={n}): n must be >= 1")
+            if self._t + n > self._horizon:
+                raise ValueError(
+                    f"session horizon exhausted: {self._t} rounds done, {n} more "
+                    f"requested, horizon {self._horizon}.  The PRNG key schedule "
+                    "is fixed at open (split is not prefix-stable) — open a new "
+                    "session with a larger round budget to continue."
                 )
-                d2_rows.append(d2_i)
-                comm_rows.append(comm_i)
-            d2, comm = jnp.stack(d2_rows), jnp.stack(comm_rows)
-        self._t += n
-        self._d2.append(d2)
-        self._comm.append(comm)
-        return d2, comm
+            sl = slice(self._t, self._t + n)
+            if self._substrate in ("batched", "clients"):
+                self._state, (d2, comm) = self._chunk_call(self._state, self._keys[:, sl])
+            else:
+                chunk = _seq_chunk_fn(self._algo, self._static_items)
+                d2_rows, comm_rows = [], []
+                for i in range(self._B):
+                    self._state[i], (d2_i, comm_i) = chunk(
+                        self._problem, self._x0, self._x_star, self._hp_i(i),
+                        self._state[i], self._keys[i, sl],
+                    )
+                    d2_rows.append(d2_i)
+                    comm_rows.append(comm_i)
+                d2, comm = jnp.stack(d2_rows), jnp.stack(comm_rows)
+            self._t += n
+            self._d2.append(d2)
+            self._comm.append(comm)
+            return d2, comm
 
     def run_until(
         self, eps: float, *, max_rounds: int | None = None, chunk: int = 32

@@ -79,3 +79,38 @@ def test_logistic_prox_gd_batched_compiles(one_chip):
         return logistic_prox_gd_batched(A, z, beta, inv_eta, 0.1, 40, interpret=False)
 
     _compile(fn, one_chip, (B, n, d), (B, d), (B,), (B,))
+
+
+def test_fused_sweep_kernel_keeps_its_name_under_the_prox_scope(one_chip):
+    """The fused SVRP round compiled for the chip: the kernel's instruction
+    keeps the name a profiler trace shows it by, and the kernel, the
+    client-block gather and the pad that feed it all run under `prox`."""
+    import re
+
+    from repro.experiments import runner as R
+    from repro.experiments.spec import RunSpec
+    from repro.problems.logistic import LogisticProblem
+
+    M, n, d, B = 4, 16, 8, 2
+    with jax.enable_x64(False):
+        prob = LogisticProblem(Z=jnp.zeros((M, n, d)), y=jnp.ones((M, n)), lam=0.1)
+        rr = RunSpec("svrp", grid={"eta": [1.0, 0.5], "p": 0.2, "smoothness": 1.0}, seeds=1,
+                     static=dict(num_steps=3, prox_solver="gd", prox_steps=3)).resolve(prob)
+        runner = R._fused_runner("svrp", tuple(sorted(rr.cfg.items())), False)
+
+        def fn(problem, x0, x_star, key_data, hp):
+            return runner(problem, x0, x_star, jax.random.wrap_key_data(key_data), hp)
+
+        args = (prob, rr.x0, rr.x_star, jax.random.key_data(rr.keys()), rr.device_hparams())
+        shapes = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), args)
+        txt = jax.jit(fn).lower(*shapes).compile().as_text()
+    lines = {m.group(2): ln for ln in txt.splitlines()
+             if (m := re.match(r"\s*(ROOT )?%(\S+) = ", ln))}
+    (kernel,) = [ln for ln in lines.values() if 'custom_call_target="tpu_custom_call"' in ln]
+    assert re.match(r"\s*(ROOT )?%logistic_prox_gd_batched\.\d+ = ", kernel), kernel[:120]
+    operands = re.search(r"custom-call\(([^)]*)\)", kernel).group(1)
+    feeds = [lines[name] for name in re.findall(r"%(\S+?)[,)]", operands + ")")]
+    assert any(re.search(r"= \S+ pad\(", ln) for ln in feeds), feeds
+    for ln in [kernel] + [f for f in feeds if "op_name=" in f]:  # bitcasts carry none
+        assert re.search(r'op_name="[^"]*/prox/', ln), ln[:200]
