@@ -17,14 +17,28 @@ Sign-folded operand: with A := y[:, None] * Z (label-signed features, one
 so padded rows (A = 0) contribute exactly nothing (sigmoid(0) scales a zero
 row) and padded columns stay 0 from the x0 = z start — no masks needed.
 
-Grid is `(B,)`: program b owns trial b's (n_pad, d_pad) block and runs the
-full `num_steps` fori_loop in VMEM; per-trial scalars (beta_b, 1/eta_b, lam,
-1/n) ride in a `(4, B)` SMEM table, and the per-trial rows (target, start,
-result) are laid out `(B, 1, d_pad)` so every VMEM block's last two dims are
-the array's (Mosaic's block-tiling rule, for any B).  VMEM budget is the A
-block: n_pad * d_pad * itemsize (a9a at f32: 1632 * 128 * 4 = 0.8 MiB,
-double-buffered).  Validated in interpret mode against
-`ref.logistic_prox_gd_batched`; compiled for a v5e by
+Layout.  Grid is `(B,)`: program b owns trial b's (n_pad, d_pad) block, both
+padded to the 128-lane width, and runs the full `num_steps` fori_loop in
+VMEM.  Per-trial scalars (beta_b, 1/eta_b, lam, 1/n) ride in a `(4, B)` SMEM
+table; the per-trial rows (target, start, result) are laid out
+`(B, 1, d_pad)` so every VMEM block's last two dims are the array's
+(Mosaic's block-tiling rule, for any B).
+
+Products on the VPU.  Before the loop the program writes its block into a
+VMEM scratch as A' (d_pad, n_pad), one 128 x 128 transpose per tile, and
+turns the target and start rows into (d_pad, 1) columns; x is carried as a
+column.  Each step walks the n_pad/128 lane tiles of A': the margins of a
+tile are `sum_j(A'_c * x)`, a sublane reduce over d; its sigmoids scale the
+same tile into a (d_pad, 128) accumulator, which one lane reduce turns into
+the gradient's column.  Both products are float32 multiplies and adds
+(`core.precision`'s full-float32 contract, with no bfloat16 split), and the
+MXU is never used: at one row per block a float32 matmul re-latches a whole
+128 x 128 tile of A as weights in each of its six passes to multiply a
+single vector, so the MXU would be fed weights, not work.
+
+VMEM budget: three (n_pad, d_pad) blocks, the double-buffered input and the
+A' scratch (a9a at f32: 3 x 1664 x 128 x 4 B = 2.6 MB).  Validated in
+interpret mode against `ref.logistic_prox_gd_batched`; compiled for a v5e by
 tests/test_tpu_compile.py.
 """
 from __future__ import annotations
@@ -36,35 +50,52 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.precision import HIGHEST
 from repro.kernels.backend import resolve_interpret
 
 _LANES = 128
-_SUBLANES = 8
 
 
 def _round_up(x: int, mult: int) -> int:
     return -(-x // mult) * mult
 
 
-def _logistic_prox_kernel(s_ref, a_ref, z_ref, x0_ref, o_ref, *, num_steps: int):
+def _logistic_prox_kernel(s_ref, a_ref, z_ref, x0_ref, o_ref, at_ref, *, num_steps: int):
     b = pl.program_id(0)
-    A = a_ref[0]  # (n_pad, d_pad) — this trial's label-signed features
-    z = z_ref[0]  # (1, d_pad) prox target
-    x0 = x0_ref[0]  # (1, d_pad) GD start (== z except for the DP noise fold)
+    d_pad, n_pad = at_ref.shape
     beta = s_ref[0, b]  # this trial's scalars: column b of the SMEM (4, B) table
     inv_eta = s_ref[1, b]
     lam = s_ref[2, b]
     inv_n = s_ref[3, b]
 
-    def gd_step(_, x):  # x: (1, d_pad)
-        # t = x A' : (1, n_pad) margins; sigmoid(-t) = 0.5 (tanh(-t/2) + 1)
-        t = jax.lax.dot_general(x, A, (((1,), (1,)), ((), ())), precision=HIGHEST)
-        u = 0.5 * (jnp.tanh(-0.5 * t) + 1.0)
-        g = -inv_n * jnp.dot(u, A, precision=HIGHEST) + lam * x
+    # A' into VMEM scratch once, one (LANES, d_pad) row tile at a time: the
+    # GD steps then read lane tiles of A' as plain (d_pad, LANES) loads.
+    for c in range(n_pad // _LANES):
+        cols = pl.ds(c * _LANES, _LANES)
+        at_ref[:, cols] = a_ref[0, cols, :].T
+
+    # Rows <-> columns by a masked reduce (an identity mask): x is carried as
+    # a (d_pad, 1) column, so both products below broadcast along lanes.
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (d_pad, d_pad), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (d_pad, d_pad), 1))
+
+    def to_col(v):  # (1, d_pad) -> (d_pad, 1)
+        return jnp.sum(jnp.where(eye, v, 0), axis=1, keepdims=True)
+
+    z = to_col(z_ref[0])  # prox target
+    x0 = to_col(x0_ref[0])  # GD start (== z except for the DP noise fold)
+
+    def gd_step(_, x):  # x: (d_pad, 1)
+        acc = jnp.zeros((d_pad, _LANES), x.dtype)
+        for c in range(n_pad // _LANES):
+            at = at_ref[:, pl.ds(c * _LANES, _LANES)]  # (d_pad, LANES) of A'
+            t = jnp.sum(at * x, axis=0, keepdims=True)  # (1, LANES) margins
+            u = 0.5 * (jnp.tanh(-0.5 * t) + 1.0)  # sigmoid(-t)
+            acc = acc + at * u
+        g = -inv_n * jnp.sum(acc, axis=1, keepdims=True) + lam * x
         return x - beta * (g + (x - z) * inv_eta)
 
-    o_ref[0] = jax.lax.fori_loop(0, num_steps, gd_step, x0)
+    x = jax.lax.fori_loop(0, num_steps, gd_step, x0)
+    o_ref[0] = jnp.sum(jnp.where(eye, x, 0), axis=0, keepdims=True)  # back to a row
 
 
 @functools.partial(jax.jit, static_argnames=("num_steps", "interpret"))
@@ -87,13 +118,13 @@ def logistic_prox_gd_batched(
     noise term folds into a SHIFTED target z' = z - eta s while the iteration
     still starts at the unshifted z (`rounds.prox_gd_fused`).  `lam` is the
     problem's shared l2 coefficient; the 1/n gradient normalization uses the
-    TRUE row count `n` (row padding to the sublane multiple is free by the
+    TRUE row count `n` (row padding to the lane multiple is free by the
     sign-folding above).
     """
     B, n, d = A.shape
     dtype = A.dtype
     d_pad = _round_up(d, _LANES)
-    n_pad = _round_up(n, _SUBLANES)
+    n_pad = _round_up(n, _LANES)
 
     def rows(v):  # (B, d) -> (B, 1, d_pad): each block's last two dims are full
         return jnp.pad(v.astype(dtype), ((0, 0), (0, d_pad - d)))[:, None, :]
@@ -122,6 +153,7 @@ def logistic_prox_gd_batched(
         ],
         out_specs=row,
         out_shape=jax.ShapeDtypeStruct((B, 1, d_pad), dtype),
+        scratch_shapes=[pltpu.VMEM((d_pad, n_pad), dtype)],
         interpret=resolve_interpret(interpret),
         name="logistic_prox_gd_batched",
     )(scalars, A_p, z_p, x0_p)

@@ -194,7 +194,8 @@ def test_prox_update_under_jit_and_traced_scalars():
 
 
 # ------------------------------------------------------- logistic prox-GD kernel
-@pytest.mark.parametrize("shape", [(2, 17, 5), (4, 64, 16), (3, 100, 123)])
+@pytest.mark.parametrize(
+    "shape", [(2, 17, 5), (4, 64, 16), (3, 100, 123), (2, 1628, 123), (2, 300, 200)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.float64])
 def test_logistic_prox_gd_batched_matches_ref(shape, dtype):
     """The in-kernel Algorithm-7 loop on the (B, n, d) logistic oracle must
@@ -213,6 +214,38 @@ def test_logistic_prox_gd_batched_matches_ref(shape, dtype):
     tol = dict(rtol=1e-5, atol=1e-6) if dtype == jnp.float32 else dict(rtol=1e-12, atol=1e-13)
     np.testing.assert_allclose(np.asarray(out), np.asarray(oracle), **tol)
     assert out.shape == (B, d) and out.dtype == dtype
+
+
+def test_logistic_prox_gd_batched_products_are_f32_off_the_mxu():
+    """At a9a's shape the kernel body holds no contraction (nothing for the
+    MXU, whose float32 passes would re-latch A per step) and no bfloat16
+    value: both products are float32 multiply-reduces on the VPU."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+
+    from repro.kernels.logistic_prox import logistic_prox_gd_batched
+
+    def eqns(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for v in eqn.params.values():
+                for sub in v if isinstance(v, (tuple, list)) else (v,):
+                    if isinstance(sub, ClosedJaxpr):
+                        yield from eqns(sub.jaxpr)
+                    elif isinstance(sub, Jaxpr):
+                        yield from eqns(sub)
+
+    B, n, d = 32, 1628, 123
+    shapes = [jax.ShapeDtypeStruct(s, jnp.float32) for s in [(B, n, d), (B, d), (B,), (B,)]]
+    outer = jax.make_jaxpr(lambda *a: logistic_prox_gd_batched(*a, 0.1, 40))(*shapes)
+    (call,) = [e for e in eqns(outer.jaxpr) if e.primitive.name == "pallas_call"]
+    assert call.params["name"] == "logistic_prox_gd_batched"
+    body = list(eqns(call.params["jaxpr"]))
+    prims = {e.primitive.name for e in body}
+    assert {"mul", "reduce_sum", "tanh"} <= prims, prims
+    assert "dot_general" not in prims, prims
+    values = list(call.params["jaxpr"].invars) + [v for e in body for v in e.invars + e.outvars]
+    dtypes = {str(v.aval.dtype) for v in values if hasattr(v.aval, "dtype")}
+    assert "bfloat16" not in dtypes and "float32" in dtypes, dtypes
 
 
 def test_logistic_prox_gd_batched_matches_prox_gd():

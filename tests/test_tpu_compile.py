@@ -72,9 +72,11 @@ def test_prox_update_batched_compiles(one_chip, B, d):
     _compile(fn, one_chip, (B, d), (B, d), (B, d), (B,), (B,))
 
 
-def test_logistic_prox_gd_batched_compiles(one_chip):
-    B, n, d = 32, 1628, 123  # a9a's 123 features, 32,561 rows over 20 clients
-
+@pytest.mark.parametrize("B,n,d", [
+    (32, 1628, 123),  # a9a's 123 features, 32,561 rows over 20 clients
+    (1, 1000, 256),  # d over one lane tile; neither n nor d a multiple of 128
+])
+def test_logistic_prox_gd_batched_compiles(one_chip, B, n, d):
     def fn(A, z, beta, inv_eta):
         return logistic_prox_gd_batched(A, z, beta, inv_eta, 0.1, 40, interpret=False)
 
