@@ -3,13 +3,16 @@
 
     python3 bench/control.py --workload <name> --seeds 1 2 3 ... [--seconds 3]
     python3 bench/control.py --workload <name> --seeds 1 2 3 --control <precision>
+    python3 bench/control.py --workload <name> --seeds 1 2 3 --fault <fault>
 
 Without `--control`, each seed runs the cell as a benchmark run does (set-up,
 a window of `--seconds`, the comparison) and prints the numbers compared:
 the lower readings.  With `--control`, the plain reference computed at
 `precision` (`bench/reference/precision.py`) takes the program's place, and
 its numbers are the upper readings: a limit has to lie between the two.
-One JSON line per seed; the benchmark's own runs never run this.
+With `--fault`, the program runs with that fault (`bench/faults.py`)
+planted underneath the timed path.  One JSON line per seed; the benchmark's
+own runs never run this.
 """
 import time
 
@@ -40,13 +43,14 @@ def readings(cell, control: str | None) -> dict:
 
 
 def main() -> int:
-    from bench import harness
+    from bench import faults, harness
 
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", type=int, nargs="+", required=True)
     ap.add_argument("--seconds", type=float, default=3.0)
     ap.add_argument("--control", default=None)
+    ap.add_argument("--fault", default=None, choices=faults.FAULTS)
     args = ap.parse_args()
     cells = [harness.cell_from_files(args.workload, s, args.seconds, False) for s in args.seeds]
     try:
@@ -56,8 +60,10 @@ def main() -> int:
         return 2
     harness.use_compile_cache()
     sys.path.insert(0, str(harness.ROOT / "src"))
+    if args.fault:
+        faults.plant(args.fault, cells[0].traffic.get("algo", "deep_svrp"))
     for cell in cells:
-        print(json.dumps(readings(cell, args.control)), flush=True)
+        print(json.dumps(dict(readings(cell, args.control), fault=args.fault)), flush=True)
         gc.collect()
     return 0
 

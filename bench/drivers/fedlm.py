@@ -3,7 +3,11 @@
 Each call runs `rounds_per_call` DeepSVRP rounds of the model over the
 clients' fixed token batches through the traffic file's channel, and the
 next call starts from the iterate the last one returned, as a fine-tuning
-job continues.  Every call does the same work.
+job continues.  A call's seed draws its rounds' anchor-refresh coins, and a
+refresh is a full gradient over the clients: so the window's i-th call takes
+the seed `WINDOW_SEED + i` in every run, and every run's window does the
+same work.  The weights, the data and the first call's seed come from the
+run's seed.
 
 Set-up builds the problem and makes the first call (the compile and the
 job's first rounds); the window continues that same job.
@@ -11,12 +15,17 @@ job's first rounds); the window continues that same job.
 End to end: `fed_round_s`, the elapsed time of every call that started in
 the window over the rounds those calls ran (calls run to completion).
 
-Correctness: the reference replays the first call from the same weights,
-data and seed in float32 at HIGHEST precision.  Compared: the mean client
-loss after each round (`loss_rel_gap`), the norm of every leaf's change over
-the call (`update_norm_gap`, each layer of a stacked leaf on its own,
-against the larger of that leaf's and the median leaf's reference norm),
-and `comm` exactly.
+Correctness: the reference replays the first call (set-up's) from the same
+weights, data and seed in float32 at HIGHEST precision.  Compared: the mean
+client loss after each of its first `LOSS_ROUNDS` rounds (`loss_rel_gap`,
+the worst of them), the norm of every leaf's change over the call
+(`update_norm_gap`: each layer of a stacked leaf on its own, against the
+larger of that leaf's and the median leaf's reference norm; leaves whose
+reference gradient is nought but for rounding are left out,
+`reference.dense_lm.moved_leaves`), and `comm` after every round, exactly.
+Later rounds' losses are not compared: rounding alone, in the reference
+too, moves the job's trajectory, and on some seeds the tenth round's loss
+reads as far from the reference's as a lower precision's does.
 """
 from __future__ import annotations
 
@@ -29,6 +38,12 @@ import numpy as np
 from bench import counts
 from bench.harness import Check, Window
 from bench.reference import dense_lm as ref
+
+# 15 refreshes in the window's first 6 calls of 10 rounds: the expectation at
+# anchor_prob 0.25.
+WINDOW_SEED = 1000
+# The rounds whose losses are compared: the first three steps of the job.
+LOSS_ROUNDS = 3
 
 
 @dataclasses.dataclass
@@ -68,14 +83,15 @@ def one_call(st: State, x, seed: int, rec):
 
 
 def replay(st: State, precision: str):
-    """The reference's (losses, comm, per-leaf change norms) of the first call."""
+    """The reference's (losses, comm, per-leaf change norms, per-leaf norms
+    of the first anchor gradient) of the first call."""
     t, lm = st.t, st.lm
     params0 = lm.weights(st.seed)
-    loss, comm, params = ref.deep_svrp(
+    loss, comm, params, grad0 = ref.deep_svrp(
         params0, lm.tokens, lm.labels, lm.sizes, eta=t["eta"], local_lr=t["local_lr"],
         local_steps=t["local_steps"], anchor_prob=t["anchor_prob"],
         rounds=t["rounds_per_call"], seed=st.first_seed, precision=precision)
-    return loss, comm, ref.leaf_change_norms(params, params0)[1]
+    return loss, comm, ref.leaf_change_norms(params, params0)[1], ref.leaf_norms(grad0)[1]
 
 
 def setup(cell, rec, control: str | None = None) -> State:
@@ -83,7 +99,7 @@ def setup(cell, rec, control: str | None = None) -> State:
     rng = np.random.default_rng(cell.seed)
     st = State(lm, cell.traffic, cell.seed, int(rng.integers(0, 2**31)), control=control)
     if control:  # the reference at a lower precision in the program's place
-        st.first_loss, st.first_comm, st.first_norms = replay(st, control)
+        st.first_loss, st.first_comm, st.first_norms, _ = replay(st, control)
         st.x = lm.x0
         return st
     x0 = lm.x0
@@ -100,7 +116,7 @@ def window(st: State, cell, rec) -> Window:
     i = 0
     while st.control is None and time.perf_counter() - start < cell.seconds:
         t0 = time.perf_counter()
-        loss, comm, st.x = one_call(st, st.x, st.first_seed + 1 + i, rec)
+        loss, comm, st.x = one_call(st, st.x, WINDOW_SEED + i, rec)
         st.calls.append((t0, time.perf_counter(), len(loss), int(comm[-1]), bool(np.all(np.isfinite(loss)))))
         i += 1
     end = time.perf_counter()
@@ -123,9 +139,10 @@ def release(st: State) -> None:
 
 
 def check(st: State, cell) -> list:
-    want_loss, want_comm, want_norms = replay(st, "highest")
-    loss_gap = float(np.max(np.abs(st.first_loss - want_loss) / np.abs(want_loss)))
-    norm_gap, _ = ref.norm_gap(st.first_norms, want_norms)
+    want_loss, want_comm, want_norms, grad_norms = replay(st, "highest")
+    got, want = st.first_loss[:LOSS_ROUNDS], want_loss[:LOSS_ROUNDS]
+    loss_gap = float(np.max(np.abs(got - want) / np.abs(want)))
+    norm_gap, _ = ref.norm_gap(st.first_norms, want_norms, ref.moved_leaves(grad_norms))
     comm_bad = int(np.sum(np.asarray(st.first_comm) != want_comm))
     lim = cell.limits
     return [Check("loss_rel_gap", loss_gap, lim["loss_rel_gap"]),

@@ -202,7 +202,8 @@ def check(st: State, cell) -> list:
     tenants = [pool[i] for i in sorted(picked)]
     lim = cell.limits
     floor_gap, comm_bad, bytes_bad, shape_bad = 0.0, 0, 0, 0
-    for ten, (want_d2, want_comm, want_bytes) in zip(tenants, replay(st, tenants, "highest")):
+    replayed = replay(st, tenants, "highest") if tenants else []
+    for ten, (want_d2, want_comm, want_bytes) in zip(tenants, replayed):
         d2, comm, comm_bytes = (np.asarray(a) for a in ten.result)
         if d2.shape != want_d2.shape:
             shape_bad += 1

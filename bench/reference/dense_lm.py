@@ -145,24 +145,26 @@ def deep_svrp(params0, tokens, labels, sz, *, eta: float, local_lr: float,
               precision: str = "highest"):
     """`rounds` DeepSVRP rounds from `params0` over clients' (tokens, labels).
 
-    Returns `(losses (rounds,), comm (rounds,), params_final)`: the mean
-    client loss after each round, the cumulative vectors communicated (3M to
-    set up the anchor, 2M a round, 2M more on a refresh), and the last
-    iterate as a parameter tree.  Each round is one compiled call."""
+    Returns `(losses (rounds,), comm (rounds,), params_final, grad0)`: the
+    mean client loss after each round, the cumulative vectors communicated
+    (3M to set up the anchor, 2M a round, 2M more on a refresh), the last
+    iterate and the first anchor gradient (the clients' mean gradient at
+    `params0`), both as parameter trees.  Each round is one compiled call."""
     x0, unravel = ravel_pytree(params0)
     M = tokens.shape[0]
     keys = jax.random.split(jax.random.key(jnp.uint32(seed)), rounds)
     coins = [bool(jax.random.bernoulli(k, F32(anchor_prob))) for k in keys]
     step = _round_fn(unravel, sz, eta, local_lr, local_steps, precision)
     full_grad = _full_grad_fn(unravel, sz, precision)
-    x, w, gbar, resid = x0, x0, full_grad(x0, tokens, labels), jnp.zeros_like(x0)
+    grad0 = full_grad(x0, tokens, labels)
+    x, w, gbar, resid = x0, x0, grad0, jnp.zeros_like(x0)
     comm, losses, comms = 3 * M, [], []
     for c in coins:
         x, w, gbar, resid, lo = step(x, w, gbar, resid, jnp.asarray(c), tokens, labels)
         comm += 2 * M + 2 * M * int(c)
         losses.append(lo)
         comms.append(comm)
-    return np.asarray(jnp.stack(losses)), np.asarray(comms), unravel(x)
+    return np.asarray(jnp.stack(losses)), np.asarray(comms), unravel(x), unravel(grad0)
 
 
 def _full_grad_fn(unravel, sz, precision):
@@ -205,27 +207,56 @@ def _round_fn(unravel, sz, eta, local_lr, local_steps, precision):
     return step
 
 
-def leaf_change_norms(params_a, params_b) -> tuple[list[str], np.ndarray]:
-    """Names and ||a - b|| of every leaf, in `jax.tree.leaves` order; each
-    layer of a stacked leaf (under "layers") counts as a leaf of its own."""
+def leaf_norms(tree) -> tuple[list[str], np.ndarray]:
+    """Names and norms of every leaf, in `jax.tree.leaves` order; each layer
+    of a stacked leaf (under "layers") counts as a leaf of its own."""
     names, norms = [], []
-    flat_a = jax.tree_util.tree_flatten_with_path(params_a)[0]
-    for (path, a), b in zip(flat_a, jax.tree.leaves(params_b)):
+    for path, a in jax.tree_util.tree_flatten_with_path(tree)[0]:
         name = "/".join(str(getattr(k, "key", k)) for k in path)
-        diff = (a - b).astype(F32)
+        a = a.astype(F32)
         if path[0].key == "layers":
-            for i, v in enumerate(jnp.linalg.norm(diff.reshape(diff.shape[0], -1), axis=1)):
+            for i, v in enumerate(jnp.linalg.norm(a.reshape(a.shape[0], -1), axis=1)):
                 names.append(f"{name}[{i}]")
                 norms.append(v)
         else:
             names.append(name)
-            norms.append(jnp.linalg.norm(diff.reshape(-1)))
+            norms.append(jnp.linalg.norm(a.reshape(-1)))
     return names, np.asarray(jnp.stack(norms), np.float64)
 
 
-def norm_gap(got: np.ndarray, want: np.ndarray) -> tuple[float, int]:
-    """Worst leaf's |got - want| over max(want, median of want), and its index."""
-    denom = np.maximum(want, np.median(want))
-    gaps = np.abs(got - want) / denom
+def leaf_change_norms(params_a, params_b) -> tuple[list[str], np.ndarray]:
+    """`leaf_norms` of `params_a - params_b`."""
+    return leaf_norms(jax.tree.map(jnp.subtract, params_a, params_b))
+
+
+def moved_leaves(grad_norms: np.ndarray, share: float = 1e-3) -> np.ndarray:
+    """Leaves whose reference gradient is more than `share` of the median
+    leaf's.  The others (a key bias that no rotation follows, under
+    softmax) have a gradient that is nought but for rounding, and move by
+    round-off alone."""
+    return grad_norms > share * np.median(grad_norms)
+
+
+def norm_gap(got: np.ndarray, want: np.ndarray, moved: np.ndarray) -> tuple[float, int]:
+    """Worst moved leaf's |got - want| over max(want, median of want), and
+    its index."""
+    denom = np.maximum(want, np.median(want[moved]))
+    gaps = np.where(moved, np.abs(got - want) / denom, 0.0)
     i = int(np.argmax(gaps))
     return float(gaps[i]), i
+
+
+def top_hessian_eig(f, x, key, iters: int = 30) -> float:
+    """|largest eigenvalue| of the Hessian of the scalar `f` at the flat `x`,
+    by power iteration on Hessian-vector products (reverse over reverse, so
+    it runs through the references' custom-VJP products)."""
+    g = jax.grad(f)
+    hvp = jax.jit(lambda v: jax.grad(lambda y: jnp.vdot(g(y), v))(x))
+    v = jax.random.normal(key, x.shape, x.dtype)
+    lam = 0.0
+    for _ in range(iters):
+        v = v / jnp.linalg.norm(v)
+        hv = hvp(v)
+        lam = float(jnp.vdot(v, hv))
+        v = hv
+    return abs(lam)
