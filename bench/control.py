@@ -61,7 +61,7 @@ def main() -> int:
     harness.use_compile_cache()
     sys.path.insert(0, str(harness.ROOT / "src"))
     if args.fault:
-        faults.plant(args.fault, cells[0].traffic.get("algo", "deep_svrp"))
+        faults.plant(args.fault, faults.round_algo(cells[0].traffic))
     for cell in cells:
         print(json.dumps(dict(readings(cell, args.control), fault=args.fault)), flush=True)
         gc.collect()

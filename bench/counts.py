@@ -75,17 +75,18 @@ def dense_lm_forward_flops(sz: dict, sequences: int, length: int) -> float:
     return tokens * (L * (proj + attn) + 2.0 * D * V)
 
 
-def deep_svrp_round_flops(sz: dict, clients: int, sequences: int, length: int,
-                          local_steps: int) -> float:
-    """One DeepSVRP round without refresh: every client's control-variate
-    gradient and `local_steps` local gradients (a gradient is a forward and
-    a backward pass, 3 forwards), and the mean client loss it reports."""
-    fwd = dense_lm_forward_flops(sz, sequences, length)
+# ----------------------------------------------------------------- DeepSVRP
+def deep_svrp_round_flops(fwd: float, clients: int, local_steps: int) -> float:
+    """One DeepSVRP round without refresh, from `fwd`, one forward pass over
+    a client's tokens: every client's control-variate gradient and
+    `local_steps` local gradients (a gradient is a forward and a backward
+    pass, 3 forwards), and the mean client loss it reports."""
     return clients * ((1 + local_steps) * 3.0 * fwd + fwd)
 
 
-def deep_svrp_refresh_flops(sz: dict, clients: int, sequences: int, length: int) -> float:
-    return clients * 3.0 * dense_lm_forward_flops(sz, sequences, length)
+def deep_svrp_refresh_flops(fwd: float, clients: int) -> float:
+    """One anchor refresh: every client's gradient at the new anchor."""
+    return clients * 3.0 * fwd
 
 
 def deep_svrp_refreshes(comm_final: int, M: int, rounds: int) -> int:

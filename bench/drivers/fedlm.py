@@ -22,10 +22,32 @@ the worst of them), the norm of every leaf's change over the call
 (`update_norm_gap`: each layer of a stacked leaf on its own, against the
 larger of that leaf's and the median leaf's reference norm; leaves whose
 reference gradient is nought but for rounding are left out,
-`reference.dense_lm.moved_leaves`), and `comm` after every round, exactly.
+`reference.deep_svrp.moved_leaves`), and `comm` after every round, exactly.
 Later rounds' losses are not compared: rounding alone, in the reference
 too, moves the job's trajectory, and on some seeds the tenth round's loss
 reads as far from the reference's as a lower precision's does.
+
+The driver knows no model: the configuration's family (`bench/families/`,
+whose docstring gives what a built family has) supplies the weights, the
+data, the program's problem, the reference loss that `reference.deep_svrp`
+runs the algorithm over, and the forward FLOPs that `bench/counts.py`'s
+round and refresh counts multiply.
+
+A new model cell on this driver is new files and new entries only:
+
+1. `bench/families/<family>.py`: `build`, returning what that docstring
+   lists, the forward FLOPs counted there;
+2. `bench/reference/<family>.py`: the plain reference and its loss,
+   importing nothing of the program;
+3. `bench/configs/<config>.json`: the sizes as run, naming the family;
+4. `bench/limits/<cell>.json`: `loss_rel_gap` and `update_norm_gap`, set
+   from the program's and the control's readings on the chip;
+5. `bench/traffic/<traffic>.json`, where the traffic is new;
+6. `bench/metrics/<metric>.py` for each per-layer metric the cell adds;
+7. in `BENCHMARK.json`: the configuration and the cell, and the cell's name
+   in `fed_round_s`'s `workloads` (and in each per-layer metric's it reports);
+8. `tests/bench/tiny/<cell>.json`: the cell's size on the CPU, which the
+   tests find by name.
 """
 from __future__ import annotations
 
@@ -37,7 +59,7 @@ import numpy as np
 
 from bench import counts
 from bench.harness import Check, Window
-from bench.reference import dense_lm as ref
+from bench.reference import deep_svrp
 
 # 15 refreshes in the window's first 6 calls of 10 rounds: the expectation at
 # anchor_prob 0.25.
@@ -48,7 +70,7 @@ LOSS_ROUNDS = 3
 
 @dataclasses.dataclass
 class State:
-    lm: object  # families.dense_lm.DenseLM
+    lm: object  # what the configuration's family built (bench/families/)
     t: dict
     seed: int
     first_seed: int
@@ -87,11 +109,12 @@ def replay(st: State, precision: str):
     of the first anchor gradient) of the first call."""
     t, lm = st.t, st.lm
     params0 = lm.weights(st.seed)
-    loss, comm, params, grad0 = ref.deep_svrp(
-        params0, lm.tokens, lm.labels, lm.sizes, eta=t["eta"], local_lr=t["local_lr"],
+    loss, comm, params, grad0 = deep_svrp.deep_svrp(
+        lm.loss, params0, lm.tokens, lm.labels, eta=t["eta"], local_lr=t["local_lr"],
         local_steps=t["local_steps"], anchor_prob=t["anchor_prob"],
         rounds=t["rounds_per_call"], seed=st.first_seed, precision=precision)
-    return loss, comm, ref.leaf_change_norms(params, params0)[1], ref.leaf_norms(grad0)[1]
+    return (loss, comm, deep_svrp.leaf_change_norms(params, params0)[1],
+            deep_svrp.leaf_norms(grad0)[1])
 
 
 def setup(cell, rec, control: str | None = None) -> State:
@@ -104,7 +127,7 @@ def setup(cell, rec, control: str | None = None) -> State:
         return st
     x0 = lm.x0
     st.first_loss, st.first_comm, x = one_call(st, x0, st.first_seed, rec)
-    st.first_norms = ref.leaf_change_norms(lm.unravel(x), lm.unravel(x0))[1]
+    st.first_norms = deep_svrp.leaf_change_norms(lm.unravel(x), lm.unravel(x0))[1]
     lm.x0 = None
     del x0
     st.x = x
@@ -123,8 +146,9 @@ def window(st: State, cell, rec) -> Window:
     t, lm = st.t, st.lm
     rounds = sum(c[2] for c in st.calls)
     M, S, Lseq = lm.tokens.shape
-    fl_round = counts.deep_svrp_round_flops(lm.sizes, M, S, Lseq, t["local_steps"])
-    fl_refresh = counts.deep_svrp_refresh_flops(lm.sizes, M, S, Lseq)
+    fwd = lm.forward_flops(S, Lseq)
+    fl_round = counts.deep_svrp_round_flops(fwd, M, t["local_steps"])
+    fl_refresh = counts.deep_svrp_refresh_flops(fwd, M)
     flops = sum(fl_refresh * (1 + counts.deep_svrp_refreshes(c[3], M, c[2])) + fl_round * c[2]
                 for c in st.calls)
     elapsed = sum(c[1] - c[0] for c in st.calls)
@@ -142,7 +166,7 @@ def check(st: State, cell) -> list:
     want_loss, want_comm, want_norms, grad_norms = replay(st, "highest")
     got, want = st.first_loss[:LOSS_ROUNDS], want_loss[:LOSS_ROUNDS]
     loss_gap = float(np.max(np.abs(got - want) / np.abs(want)))
-    norm_gap, _ = ref.norm_gap(st.first_norms, want_norms, ref.moved_leaves(grad_norms))
+    norm_gap, _ = deep_svrp.norm_gap(st.first_norms, want_norms, deep_svrp.moved_leaves(grad_norms))
     comm_bad = int(np.sum(np.asarray(st.first_comm) != want_comm))
     lim = cell.limits
     return [Check("loss_rel_gap", loss_gap, lim["loss_rel_gap"]),

@@ -14,6 +14,7 @@ import jax
 import numpy as np
 from jax.flatten_util import ravel_pytree
 
+from bench import counts
 from bench.reference import dense_lm as ref
 
 # The configuration file's keys -> the program's ModelConfig fields.
@@ -38,6 +39,13 @@ class DenseLM:
     def weights(self, seed: int):
         """The seeded weights again, as a parameter tree."""
         return _weights_fn(tuple(sorted(self.sizes.items())))(_key(seed, 0))
+
+    def loss(self, params, tokens, labels, precision: str):
+        """The plain reference's loss (`bench/reference/dense_lm.py`)."""
+        return ref.loss(params, tokens, labels, self.sizes, precision)
+
+    def forward_flops(self, sequences: int, length: int) -> float:
+        return counts.dense_lm_forward_flops(self.sizes, sequences, length)
 
 
 def _key(seed: int, stream: int):

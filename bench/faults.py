@@ -60,6 +60,12 @@ def _half_mean(self, y):
     return jnp.mean(y[..., : y.shape[-2] // 2, :], axis=-2)
 
 
+def round_algo(traffic: dict) -> str:
+    """The program's round that a traffic mix's runs go through: its `algo`,
+    else DeepSVRP's (the `fedlm` driver names no other)."""
+    return traffic.get("algo", "deep_svrp")
+
+
 def plant(fault: str, algo: str, patch=_Patch) -> None:
     """Plant `fault` in the program's round `algo` (or in its client mean),
     through `patch`'s `setattr` / `setitem`."""
